@@ -31,9 +31,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
-import textwrap
 import time
 
 import numpy as np
@@ -46,6 +44,7 @@ from repro.configs.registry import (KV_PAGE_CHAINS, PIPELINES,
 from repro.core.pipeline import parse_pipeline
 from repro.core.select import get_kv_selector, get_selector
 from repro.compression.kv import kv_quantizer_config, pack_kv, quantize_kv
+from repro.launch.cache import use_compile_cache
 from repro.runtime import guard
 
 from . import datasets
@@ -166,38 +165,24 @@ def detection(smoke: bool) -> list:
 
 # in-flight §12 coverage: the per-hop plane checksums of the verified
 # ring reduce (Transport.reduce_mean(integrity='drop')) against a
-# `hop_bitflip` fault hook.  Runs in a subprocess so XLA_FLAGS can
-# emulate a 2-device mesh regardless of this process's backend state.
-_RING_SCRIPT = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
+# `hop_bitflip` fault hook, on this process's own devices.
+def ring_detection() -> dict:
+    """`hop_bitflip` row: clean ring keeps every contribution (no false
+    positives); a corrupted hop is dropped on every receiving rank.
+    Needs two devices: on CPU, start the process with
+    XLA_FLAGS=--xla_force_host_platform_device_count=2."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.compression.grads import GradCompressionConfig, compress_shard
     from repro.core.transport import TRANSPORT, Transport
-    from repro.runtime.guard import FaultPlan
 
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((2,), ("pod",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    else:
-        mesh = jax.make_mesh((2,), ("pod",))
-    if hasattr(jax, "shard_map"):
-        def smap(f):
-            return jax.shard_map(f, mesh=mesh, in_specs=P("pod", None),
-                                 out_specs=(P("pod", None), P("pod")),
-                                 axis_names={"pod"}, check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def smap(f):
-            return _shard_map(f, mesh=mesh, in_specs=P("pod", None),
-                              out_specs=(P("pod", None), P("pod")),
-                              check_rep=False)
-
+    if len(jax.devices()) < 2:
+        raise RuntimeError(
+            "the ring detection row needs 2 devices, this process has "
+            f"{len(jax.devices())}; on CPU set XLA_FLAGS="
+            "--xla_force_host_platform_device_count=2 before JAX starts")
+    mesh = jax.make_mesh((2,), ("pod",), devices=jax.devices()[:2],
+                         axis_types=(jax.sharding.AxisType.Auto,))
     # bin_bits=16 keeps the shards outlier-free so the §8 ring fires
     # (outliers would route the reduce to the gather fallback instead)
     cfg = GradCompressionConfig(eb_rel=2.0 ** -6, bin_bits=16,
@@ -210,38 +195,22 @@ _RING_SCRIPT = textwrap.dedent("""
             mean, nv = tp.reduce_mean(shard.enc, pipe, n, "pod",
                                       integrity="drop", return_valid=True)
             return mean, nv[None]
+        smap = jax.shard_map(f, mesh=mesh, in_specs=P("pod", None),
+                             out_specs=(P("pod"), P("pod")),
+                             axis_names={"pod"}, check_vma=False)
         gd = jax.device_put(jnp.asarray(g),
                             NamedSharding(mesh, P("pod", None)))
-        mean, nv = jax.jit(smap(f))(gd)
+        mean, nv = jax.jit(smap)(gd)
         return np.asarray(mean), np.asarray(nv).tolist()
 
     r = np.random.default_rng(__import__("zlib").crc32(b"ring-hop"))
     g = np.broadcast_to((r.standard_normal(n) * 1e-2).astype(np.float32),
                         (2, n)).copy()
-    mean_c, valid_c = run(TRANSPORT, g)
-    plan = FaultPlan("ring", "hop_bitflip")
-    mean_f, valid_f = run(Transport(fault=plan.corrupt_hop), g)
-    print("CLEAN", *valid_c)
-    print("FAULT", *valid_f)
-    assert np.all(np.isfinite(mean_f))
-""")
-
-
-def ring_detection() -> dict:
-    """`hop_bitflip` row: clean ring keeps every contribution (no false
-    positives); a corrupted hop is dropped on every receiving rank."""
-    proc = subprocess.run(
-        [sys.executable, "-c", _RING_SCRIPT], capture_output=True,
-        text=True, env={**os.environ, "PYTHONPATH": os.path.join(
-            os.path.dirname(__file__), "..", "src")})
-    if proc.returncode != 0:
-        print(proc.stdout + proc.stderr, file=sys.stderr)
-        return _detection_row("transport", "ring:reduce_mean",
-                              {"hop_bitflip": False}, False)
-    lines = dict(ln.split(" ", 1) for ln in
-                 proc.stdout.strip().splitlines() if " " in ln)
-    clean = [int(v) for v in lines.get("CLEAN", "").split()]
-    fault = [int(v) for v in lines.get("FAULT", "").split()]
+    _, clean = run(TRANSPORT, g)
+    plan = guard.FaultPlan("ring", "hop_bitflip")
+    mean_f, fault = run(Transport(fault=plan.corrupt_hop), g)
+    if not np.all(np.isfinite(mean_f)):
+        raise AssertionError("degraded ring mean is not finite")
     clean_ok = clean == [2, 2]
     detected = bool(fault) and all(v < 2 for v in fault)
     return _detection_row("transport", "ring:reduce_mean",
@@ -289,6 +258,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=OUT_DEFAULT,
                     help="artifact path (default: repo BENCH_audit.json)")
     args = ap.parse_args(sys.argv[1:] if argv is None else argv)
+    use_compile_cache()
 
     det = detection(args.smoke)
     ovh = overhead(args.smoke)

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import select as SEL
+from repro.launch.cache import use_compile_cache
 
 from . import datasets
 
@@ -223,6 +224,7 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default=str(_REPO_ROOT / "BENCH_select.json"),
                     help="where to write the per-suite rows")
     args = ap.parse_args(sys.argv[1:] if argv is None else argv)
+    use_compile_cache()
 
     from repro.configs.registry import SELECTOR_SETS
 

@@ -42,6 +42,7 @@ import jax                                               # noqa: E402
 
 from repro.configs import registry                       # noqa: E402
 from repro.configs.registry import get_kv_chain          # noqa: E402
+from repro.launch.cache import use_compile_cache         # noqa: E402
 from repro.models import build                           # noqa: E402
 from repro.models import engine as E                     # noqa: E402
 from repro.models import serve as S                      # noqa: E402
@@ -145,6 +146,7 @@ def main():
     ap.add_argument("--out", default=os.path.join(ROOT,
                                                   "BENCH_decode.json"))
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.smoke:
         defaults = dict(slots=2, seq=256, requests=3, prompt_len=130,

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import QuantizerConfig, roundtrip_dense
+from repro.launch.cache import use_compile_cache
 
 from .datasets import _rng
 
@@ -66,6 +67,7 @@ def main():
                     help="CI subset: the exponent-boundary slabs plus "
                          "4 random ones (same flag grammar as run.py)")
     args = ap.parse_args()
+    use_compile_cache()
 
     total_slabs = (1 << 32) // SLAB
     if args.full:

@@ -47,6 +47,7 @@ from repro.core import (QuantizerConfig, compression_ratio, decode_dense,
                         encode_dense, roundtrip_dense, serialize)
 from repro.core.quantizer import (quantize_abs, quantize_abs_unprotected,
                                   quantize_rel, quantize_rel_library)
+from repro.launch.cache import use_compile_cache
 
 from . import datasets
 
@@ -495,11 +496,12 @@ def lossless(pipeline: str | None = None, smoke: bool = False):
               f"vs_packed={pk.nbytes() / float(lc.wire_nbytes()):.2f}x "
               f"vs_f32={cache.nbytes / float(lc.wire_nbytes()):.2f}x")
 
-    # Pallas fused dispatch vs jit reference: bit-identical in interpret
+    # Pallas fused dispatch vs jit reference: bit-identical (compiled on
+    # a TPU, interpret mode elsewhere)
     x = jnp.asarray(datasets.GRAD_SUITES["gradsmooth"]()[:1 << 19])
     pipe = parse_pipeline("abs:1e-05:cap=0.015625|pack:16|narrow")
     ref = pipe.encode(x, kernels=False)
-    ker = pipe.encode(x, kernels=True, interpret=True)
+    ker = pipe.encode(x, kernels=True)
     same = all(
         (a is None and b is None) or (np.array_equal(np.asarray(a),
                                                      np.asarray(b))
@@ -592,6 +594,7 @@ def main(argv=None) -> None:
                     help="small datasets / single repeats for the "
                          "`lossless` table (CI)")
     args = ap.parse_args(sys.argv[1:] if argv is None else argv)
+    use_compile_cache()
     names = args.names or list(TABLES)
     unknown = [n for n in names if n not in TABLES]
     if unknown:

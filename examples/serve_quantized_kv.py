@@ -41,13 +41,9 @@ def cache_bytes(tree):
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names={"wire"},
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={"wire"},
+                         check_vma=False)
 
 
 def disaggregate(quant, stages="zero"):

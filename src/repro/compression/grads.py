@@ -245,7 +245,7 @@ def compressed_mean(g: jnp.ndarray, cfg: GradCompressionConfig, axis: str,
     # all pods must take the same branch: agree by pmax
     any_overflow = jax.lax.pmax(shard.enc.overflow.astype(jnp.int32),
                                 axis) > 0
-    p = jax.lax.psum(1, axis)        # axis size (jax.lax.axis_size compat)
+    p = jax.lax.axis_size(axis)
 
     if integrity == "drop":
         def _verified_mean(_):

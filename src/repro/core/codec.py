@@ -660,6 +660,9 @@ ENT_SYMS = 256                 # byte alphabet
 _ENT_CHUNK_SYMS = 4 * LC_CHUNK            # 2048 coded bytes per chunk
 _ENT_CHUNK_CAP_BITS = 32 * LC_CHUNK       # verbatim-escape threshold
 _ENT_BUF_WORDS = _ENT_CHUNK_SYMS * ENT_MAX_LEN // 32   # worst-case coded
+# chunks per lax.map step of the ent coder: a step's byte-symbol planes
+# stay at a few MiB however long the stream (a 512^3 field is 2^18 chunks)
+_ENT_MAP_CHUNKS = 2048
 
 # Static bit-reversal table for ENT_MAX_LEN-bit values (the canonical
 # code is MSB-first; the stream is LSB-first — see the bit-order note).
@@ -770,10 +773,12 @@ def ent_decode_lut(lens: jnp.ndarray):
 
 def _ent_chunk_bytes(chunks: jnp.ndarray) -> jnp.ndarray:
     """uint32[nc, LC_CHUNK] -> int32[nc, 4*LC_CHUNK] byte symbols in
-    stream order (little-endian within each word)."""
-    b = jnp.stack([(chunks >> jnp.uint32(8 * j)) & jnp.uint32(0xFF)
-                   for j in range(4)], axis=-1)
-    return b.reshape(chunks.shape[0], _ENT_CHUNK_SYMS).astype(jnp.int32)
+    stream order (little-endian within each word).  Built lane-dense:
+    a [..., 4] minor axis would pad 32x in TPU tiles."""
+    k = np.arange(_ENT_CHUNK_SYMS)
+    src = jnp.take(chunks, jnp.asarray(k // 4), axis=1)
+    shift = jnp.asarray((k % 4) * 8, jnp.uint32)
+    return ((src >> shift) & jnp.uint32(0xFF)).astype(jnp.int32)
 
 
 def encode_words_ent(words: jnp.ndarray):
@@ -786,34 +791,40 @@ def encode_words_ent(words: jnp.ndarray):
     wpad = jnp.pad(words, (0, nc * LC_CHUNK - n_words))
     chunks = wpad.reshape(nc, LC_CHUNK)
     alive = jnp.max(chunks, axis=1) > 0
-    byts = _ent_chunk_bytes(chunks)
+
+    def chunk_hist(c):
+        b = _ent_chunk_bytes(c[None])[0]
+        return jnp.zeros(ENT_SYMS, jnp.int32).at[b].add(1)
+
     # codebook from the byte histogram of SURVIVING chunks only — zero
     # chunks are dropped whole and must not skew the code lengths
-    hist = jnp.zeros(ENT_SYMS, jnp.int32).at[byts.reshape(-1)].add(
-        jnp.repeat(alive.astype(jnp.int32), _ENT_CHUNK_SYMS))
+    hist = jnp.sum(jnp.where(alive[:, None], jax.lax.map(
+        chunk_hist, chunks, batch_size=_ENT_MAP_CHUNKS), 0), axis=0)
     lens = ent_code_lengths(hist)
     sym_len, sym_code = ent_encode_table(lens)
 
-    # per-chunk bitstream: cumsum the code lengths, deposit each code's
-    # <= 2 word fragments by scatter-ADD (bits are disjoint, so add == or)
-    lns = sym_len[byts]
-    ends = jnp.cumsum(lns, axis=1)
-    offs = ends - lns
-    bitlen = ends[:, -1]
-    code = sym_code[byts]
-    w_idx = offs >> 5
-    boff = (offs & 31).astype(jnp.uint32)
-    lo = code << boff
-    hi = jnp.where(boff > 0,
-                   code >> jnp.where(boff > 0, jnp.uint32(32) - boff,
-                                     jnp.uint32(1)),
-                   jnp.uint32(0))
-
-    def deposit(wi, lo_, hi_):
+    def code_chunk(c):
+        # per-chunk bitstream: cumsum the code lengths, deposit each
+        # code's <= 2 word fragments by scatter-ADD (bits are disjoint,
+        # so add == or)
+        b = _ent_chunk_bytes(c[None])[0]
+        lns = sym_len[b]
+        ends = jnp.cumsum(lns)
+        offs = ends - lns
+        code = sym_code[b]
+        w_idx = offs >> 5
+        boff = (offs & 31).astype(jnp.uint32)
+        lo = code << boff
+        hi = jnp.where(boff > 0,
+                       code >> jnp.where(boff > 0, jnp.uint32(32) - boff,
+                                         jnp.uint32(1)),
+                       jnp.uint32(0))
         buf = jnp.zeros((_ENT_BUF_WORDS + 1,), jnp.uint32)
-        return buf.at[wi].add(lo_).at[wi + 1].add(hi_)
+        buf = buf.at[w_idx].add(lo).at[w_idx + 1].add(hi)
+        return buf[:LC_CHUNK], ends[-1]
 
-    coded = jax.vmap(deposit)(w_idx, lo, hi)[:, :LC_CHUNK]
+    coded, bitlen = jax.lax.map(code_chunk, chunks,
+                                batch_size=_ENT_MAP_CHUNKS)
     modes = jnp.where(~alive, 0,
                       jnp.where(bitlen <= _ENT_CHUNK_CAP_BITS, 1, 2)
                       ).astype(jnp.int32)
@@ -872,11 +883,11 @@ def decode_words_ent(header_words: jnp.ndarray, payload: jnp.ndarray,
 
         _, syms = jax.lax.scan(step, jnp.int32(0), None,
                                length=_ENT_CHUNK_SYMS)
-        b = syms.reshape(LC_CHUNK, 4)
-        return (b[:, 0] | (b[:, 1] << jnp.uint32(8))
-                | (b[:, 2] << jnp.uint32(16)) | (b[:, 3] << jnp.uint32(24)))
+        return (syms[0::4] | (syms[1::4] << jnp.uint32(8))
+                | (syms[2::4] << jnp.uint32(16))
+                | (syms[3::4] << jnp.uint32(24)))
 
-    decoded = jax.vmap(dec_chunk)(buf)
+    decoded = jax.lax.map(dec_chunk, buf, batch_size=_ENT_MAP_CHUNKS)
     m = modes[:, None]
     out = jnp.where(m == 1, decoded,
                     jnp.where(m == 2, padded, jnp.uint32(0)))

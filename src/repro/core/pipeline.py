@@ -55,6 +55,7 @@ tests force it with `kernels=True, interpret=True`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import NamedTuple
 
 import jax
@@ -314,6 +315,10 @@ def word_stage_sizes(stages, n_words: int) -> list:
     return sizes
 
 
+# The word-stage chains are jitted whole (stages and sizes static): run
+# op by op, an archival-size plane materializes every intermediate — the
+# `ent` stage expands 2^27 words to 2^29 int32 byte symbols per array.
+@functools.partial(jax.jit, static_argnums=(0, 2))
 def encode_word_stages(stages, words, n_words: int):
     """Run a word-stage chain over a packed plane (reusable on any word
     stream — gradient shards, KV pages).  Returns (headers tuple,
@@ -327,6 +332,7 @@ def encode_word_stages(stages, words, n_words: int):
     return tuple(headers), cur, plen
 
 
+@functools.partial(jax.jit, static_argnums=(0, 3))
 def decode_word_stages(stages, headers, payload, n_words: int):
     """Exact inverse of encode_word_stages."""
     sizes = word_stage_sizes(stages, n_words)

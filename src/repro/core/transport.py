@@ -63,16 +63,11 @@ from .select import SelectedWire
 
 def axis_size_static(axis) -> int | None:
     """Static size of a named mesh axis (needed to build a ring perm), or
-    None when this JAX cannot resolve it — callers fall back to gather."""
+    None outside a context that binds it — callers fall back to gather."""
     try:
-        size = jax.lax.axis_size(axis)                 # newer JAX
-    except (AttributeError, NameError):
-        try:
-            from jax.core import axis_frame            # 0.4.x: returns int
-            size = axis_frame(axis)
-        except Exception:
-            return None
-    return int(size) if isinstance(size, int) else None
+        return int(jax.lax.axis_size(axis))
+    except NameError:                                  # unbound axis name
+        return None
 
 
 # ------------------------------------------------------ byte accounting ---
@@ -316,7 +311,7 @@ class Transport:
         the observable `benchmarks/audit_bench.py`'s ring detection row
         pins."""
         if integrity is None:
-            p = jax.lax.psum(1, axis)      # axis size (old-JAX compatible)
+            p = jax.lax.axis_size(axis)
             mean = self.reduce_sum(enc, pipe, n, axis) / p
             return (mean, jax.lax.psum(jnp.int32(1), axis)) \
                 if return_valid else mean

@@ -43,7 +43,7 @@ def _rel_kernel(bins_ref, payload_ref, out_mask_ref, sign_ref, y_ref, *,
 
 
 def dequantize_abs_pallas(bins2d, payload2d, outlier2d, eb, *, dtype,
-                          eb_floor, rows=DEFAULT_ROWS, interpret=True):
+                          eb_floor, rows=DEFAULT_ROWS, interpret):
     r_total, lanes = bins2d.shape
     assert lanes == LANES and r_total % rows == 0
     spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
@@ -58,7 +58,7 @@ def dequantize_abs_pallas(bins2d, payload2d, outlier2d, eb, *, dtype,
 
 
 def dequantize_rel_pallas(bins2d, payload2d, outlier2d, sign2d, *, cfg,
-                          dtype, rows=DEFAULT_ROWS, interpret=True):
+                          dtype, rows=DEFAULT_ROWS, interpret):
     r_total, lanes = bins2d.shape
     assert lanes == LANES and r_total % rows == 0
     _, log_step, _ = cfg.rel_constants()

@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .pack import _use_interpret
+
 NEG_BIG = -1e30
 
 
@@ -92,9 +94,11 @@ def _kernel(len_ref, q_ref, kb_ref, keb_ref, ki_ref, kv_ref_,
 
 
 def kv_decode_attention(q, kq, vq, lengths, *, page=128, cap=8,
-                        interpret=True):
+                        interpret=None):
     """q: [B, G, Hg, D]; kq/vq: compression.kv.QuantizedKV with
-    bins [B, G, S, D]; lengths: int32 [B].  Returns [B, G, Hg, D]."""
+    bins [B, G, S, D]; lengths: int32 [B].  Returns [B, G, Hg, D].
+    interpret=None compiles on TPU and interprets elsewhere."""
+    interpret = _use_interpret() if interpret is None else interpret
     b, g, hg, d = q.shape
     s = kq.bins.shape[2]
     assert s % page == 0

@@ -21,8 +21,9 @@ zero/narrow scheme of DESIGN.md §6 (reference: core.codec.encode_words_lc):
     reference (core.codec.lc_compact_payload / lc_gather_chunks), which
     is what makes kernel and reference bit-identical by construction.
 
-Everything validates in interpret mode on CPU (tests/test_lossless.py);
-block shapes are TPU-native but unmeasured on hardware.
+Everything validates in interpret mode on CPU (tests/test_lossless.py),
+compiles for a TPU v5e (tests/test_tpu_compile.py) and runs bit-identical
+to the reference on one (chip_smoke.py); its speed is not measured yet.
 """
 from __future__ import annotations
 
@@ -36,9 +37,9 @@ from repro.core import QuantizerConfig
 from repro.core import codec as C
 from repro.core.bitops import float_to_bits
 
-from .pack import (LANES, _abs_quantize_block, _narrow_mask, _pack_block,
-                   _rel_quantize_block, _tile_words, _unpack_block,
-                   _use_interpret)
+from .pack import (LANES, _abs_quantize_block, _eb_row, _narrow_mask,
+                   _pack_block, _rel_quantize_block, _tile_words,
+                   _unpack_block, _use_interpret)
 from .quantize_abs import DEFAULT_ROWS
 
 CHUNK_ROWS = C.LC_CHUNK // LANES        # word rows per chunk (= 4)
@@ -54,14 +55,15 @@ def _chunk_select_block(words, stage):
     wrows = words.shape[0]
     nck = wrows // CHUNK_ROWS
     grp = words.reshape(nck, CHUNK_ROWS, LANES)
-    mx = jnp.max(grp, axis=(1, 2))                         # [nck]
-    zero = mx == 0
+    # per-word width code, reduced in int32 (Mosaic has no unsigned
+    # reductions): a chunk's code is its widest word's
     if stage == "zero":
-        codes = jnp.where(zero, 0, 3)
+        wcode = jnp.where(grp != 0, 3, 0)
     else:
-        codes = jnp.where(zero, 0,
-                          jnp.where(mx < (1 << 8), 1,
-                                    jnp.where(mx < (1 << 16), 2, 3)))
+        wcode = ((grp != 0).astype(jnp.int32) + (grp >= (1 << 8))
+                 + (grp >= (1 << 16)))
+    codes = jnp.max(jnp.max(wcode.astype(jnp.int32), axis=2, keepdims=True),
+                    axis=1, keepdims=True)                  # [nck, 1, 1]
     # CHUNK_ROWS == vpw at width 8 and 2*vpw at width 16, so the whole-block
     # _pack_block groups exactly one chunk per candidate row group — same
     # grouping as the reference's full-stream pack_words.
@@ -71,11 +73,10 @@ def _chunk_select_block(words, stage):
     z2 = jnp.zeros((nck, CHUNK_ROWS - 2, LANES), jnp.uint32)
     pad1 = jnp.concatenate([cand1, z1], axis=1)
     pad2 = jnp.concatenate([cand2, z2], axis=1)
-    cb = codes[:, None, None]
-    sel = jnp.where(cb == 1, pad1,
-                    jnp.where(cb == 2, pad2,
-                              jnp.where(cb == 3, grp, jnp.uint32(0))))
-    codes_b = jnp.broadcast_to(codes.astype(jnp.uint32)[:, None],
+    sel = jnp.where(codes == 1, pad1,
+                    jnp.where(codes == 2, pad2,
+                              jnp.where(codes == 3, grp, jnp.uint32(0))))
+    codes_b = jnp.broadcast_to(codes.astype(jnp.uint32)[:, 0, :],
                                (nck, LANES))
     return sel.reshape(wrows, LANES), codes_b
 
@@ -113,7 +114,7 @@ def _abs_pack_lc_kernel(x_ref, eb_ref, words_ref, out_ref, sel_ref,
     """Quantize + pack + chunk-narrow in ONE pass over x (DESIGN.md §3/§6:
     elementwise codec work is memory-bound, so the lossless scan rides the
     same HBM stream the pack already pays for)."""
-    bins, outlier = _abs_quantize_block(x_ref[...], eb_ref[0, 0],
+    bins, outlier = _abs_quantize_block(x_ref[...], _eb_row(eb_ref),
                                         maxbin=maxbin, tighten=tighten,
                                         eb_floor=eb_floor)
     words = _pack_block(bins.astype(jnp.uint32) & _narrow_mask(bin_bits),
@@ -151,7 +152,7 @@ def _check_wrows(wrows):
 
 
 def chunk_select_pallas(words2d, stage, *, wrows=DEFAULT_ROWS,
-                        interpret=True):
+                        interpret):
     """words2d: uint32[W_total, 128], W_total % wrows == 0.  Returns
     (sel [W_total, 128], codes [W_total/CHUNK_ROWS, 128])."""
     w_total, lanes = words2d.shape
@@ -174,7 +175,7 @@ def chunk_select_pallas(words2d, stage, *, wrows=DEFAULT_ROWS,
 
 
 def chunk_expand_pallas(padded2d, codes2d, *, wrows=DEFAULT_ROWS,
-                        interpret=True):
+                        interpret):
     w_total, lanes = padded2d.shape
     _check_wrows(wrows)
     assert lanes == LANES and w_total % wrows == 0

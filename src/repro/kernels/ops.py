@@ -19,12 +19,9 @@ from repro.core.quantizer import Quantized
 from . import dequantize as _dq
 from . import quantize_abs as _qa
 from . import quantize_rel as _qr
+from .pack import _use_interpret
 
 LANES = _qa.LANES
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _tile(x: jnp.ndarray, rows: int, pad_value=1.0):

@@ -26,6 +26,9 @@ full-width bins are ever materialized (outliers ride the capped
 (idx, payload) table; the REL sign plane packs at 1 bit/value vs a
 byte-wide bool).
 
+On a TPU these kernels are f32 only: Mosaic refuses 64-bit types, so
+the f64 branches below run in interpret mode and nowhere else.
+
 The device-side lossless stage (DESIGN.md §6) rides this same HBM pass:
 kernels/lossless.py reuses _abs/_rel_quantize_block and _pack_block below
 to fuse quantize + pack + per-chunk zero-detection/width-narrowing into
@@ -84,18 +87,32 @@ def _narrow_mask(bin_bits):
 
 # ---------------------------------------------------- fused quantize+pack --
 
+def _eb_row(eb_ref):
+    """The (1, 1) bound operand as a (1, LANES) vector.  Mosaic bitcasts
+    vectors only, so the pow2 step is derived on a row that broadcasts
+    over the block's sublanes."""
+    return jnp.broadcast_to(eb_ref[...], (1, LANES))
+
+
+def _abs_step(eb_in, dt, eb_floor):
+    """(eb clamped to the FTZ floor, pow2-floored step 2*eb) — the
+    bit-exact twin of core.quantizer's step; pow2 makes it FMA-immune."""
+    eb = jnp.maximum(eb_in, jnp.asarray(eb_floor, dt))
+    mant_mask = (1 << 23) - 1 if dt == jnp.float32 else (1 << 52) - 1
+    int_t = jnp.int32 if dt == jnp.float32 else jnp.int64
+    eb2 = lax.bitcast_convert_type(
+        lax.bitcast_convert_type(jnp.asarray(2.0, dt) * eb, int_t) & ~mant_mask,
+        dt)
+    return eb, eb2
+
+
 def _abs_quantize_block(x, eb_in, *, maxbin, tighten, eb_floor):
     """In-kernel ABS quantize math (bit-exact twin of core.quantizer).
     Returns (bins int32 with outliers zeroed, outlier bool).  Shared by the
     pack kernels here and the fused lossless kernels (kernels/lossless.py)."""
     dt = x.dtype
     degenerate = ~(eb_in >= eb_floor)            # FTZ guard (see core.config)
-    eb = jnp.maximum(eb_in, eb_floor)
-    mant_mask = (1 << 23) - 1 if dt == jnp.float32 else (1 << 52) - 1
-    int_t = jnp.int32 if dt == jnp.float32 else jnp.int64
-    eb2 = lax.bitcast_convert_type(
-        lax.bitcast_convert_type(jnp.asarray(2.0, dt) * eb, int_t) & ~mant_mask,
-        dt)                                      # pow2 step -> FMA-immune
+    eb, eb2 = _abs_step(eb_in, dt, eb_floor)
     inv_eb2 = jnp.asarray(1.0, dt) / eb2
 
     finite = jnp.isfinite(x)
@@ -140,7 +157,7 @@ def _rel_quantize_block(x, *, maxbin, tighten, eb, log_step, inv_log_step,
 
 def _abs_pack_kernel(x_ref, eb_ref, words_ref, out_ref, *, maxbin, tighten,
                      eb_floor, bin_bits):
-    bins, outlier = _abs_quantize_block(x_ref[...], eb_ref[0, 0],
+    bins, outlier = _abs_quantize_block(x_ref[...], _eb_row(eb_ref),
                                         maxbin=maxbin, tighten=tighten,
                                         eb_floor=eb_floor)
     words_ref[...] = _pack_block(
@@ -167,12 +184,7 @@ def _rel_pack_kernel(x_ref, words_ref, out_ref, sign_words_ref, *, maxbin,
 
 def _abs_unpack_kernel(words_ref, eb_ref, y_ref, *, eb_floor, bin_bits):
     dt = y_ref.dtype
-    eb = jnp.maximum(eb_ref[0, 0], jnp.asarray(eb_floor, dt))
-    mant_mask = (1 << 23) - 1 if dt == jnp.float32 else (1 << 52) - 1
-    int_t = jnp.int32 if dt == jnp.float32 else jnp.int64
-    eb2 = lax.bitcast_convert_type(
-        lax.bitcast_convert_type(jnp.asarray(2.0, dt) * eb, int_t) & ~mant_mask,
-        dt)                                      # pow2 step, matches encoder
+    _, eb2 = _abs_step(_eb_row(eb_ref), dt, eb_floor)   # matches the encoder
     bins = _unpack_block(words_ref[...], 32 // bin_bits, bin_bits)
     y_ref[...] = bins.astype(dt) * eb2           # exact
 
@@ -189,6 +201,8 @@ def _rel_unpack_kernel(words_ref, sign_words_ref, y_ref, *, log_step, mb,
 # -------------------------------------------------------------- wrappers --
 
 def _use_interpret() -> bool:
+    """The one backend switch for every kernel entry point: compiled by
+    Mosaic on TPU, the Pallas interpreter elsewhere (CPU tests)."""
     return jax.default_backend() != "tpu"
 
 
@@ -199,7 +213,7 @@ def _check_rows(rows):
 
 
 def quantize_pack_abs_pallas(x2d, eb, *, maxbin, tighten, eb_floor, bin_bits,
-                             rows=DEFAULT_ROWS, interpret=True):
+                             rows=DEFAULT_ROWS, interpret):
     """x2d: [R_total, 128], R_total % rows == 0.  eb: [1, 1].
     Returns (words [R_total/vpw, 128] uint32, outlier [R_total, 128])."""
     r_total, lanes = x2d.shape
@@ -228,7 +242,7 @@ def quantize_pack_abs_pallas(x2d, eb, *, maxbin, tighten, eb_floor, bin_bits,
     )(x2d, eb)
 
 
-def quantize_pack_rel_pallas(x2d, *, cfg, rows=DEFAULT_ROWS, interpret=True):
+def quantize_pack_rel_pallas(x2d, *, cfg, rows=DEFAULT_ROWS, interpret):
     """Returns (words [R/vpw, 128], outlier [R, 128], sign_words [R/32, 128])."""
     import numpy as np
 
@@ -263,7 +277,7 @@ def quantize_pack_rel_pallas(x2d, *, cfg, rows=DEFAULT_ROWS, interpret=True):
 
 
 def unpack_dequant_abs_pallas(words2d, eb, *, dtype, eb_floor, bin_bits,
-                              rows=DEFAULT_ROWS, interpret=True):
+                              rows=DEFAULT_ROWS, interpret):
     """words2d: [W_total, 128] with W_total % (rows/vpw) == 0.
     Returns recon [W_total*vpw, 128] (outliers NOT restored — the caller
     scatters the capped exact table afterwards)."""
@@ -287,7 +301,7 @@ def unpack_dequant_abs_pallas(words2d, eb, *, dtype, eb_floor, bin_bits,
 
 
 def unpack_dequant_rel_pallas(words2d, sign_words2d, *, cfg, dtype,
-                              rows=DEFAULT_ROWS, interpret=True):
+                              rows=DEFAULT_ROWS, interpret):
     w_total, lanes = words2d.shape
     _check_rows(rows)
     vpw = 32 // cfg.bin_bits

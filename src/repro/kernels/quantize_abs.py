@@ -63,7 +63,7 @@ def _kernel(x_ref, eb_ref, bins_ref, out_ref, recon_ref, *, maxbin, tighten,
 
 def quantize_abs_pallas(x2d: jnp.ndarray, eb: jnp.ndarray, *, maxbin: int,
                         tighten: float, eb_floor: float,
-                        rows: int = DEFAULT_ROWS, interpret: bool = True):
+                        rows: int = DEFAULT_ROWS, interpret: bool):
     """x2d: [R_total, 128] with R_total % rows == 0.  eb: [1, 1]."""
     r_total, lanes = x2d.shape
     assert lanes == LANES and r_total % rows == 0

@@ -69,7 +69,7 @@ def _kernel(x_ref, bins_ref, out_ref, recon_ref, sign_ref, *, maxbin, tighten,
 
 
 def quantize_rel_pallas(x2d: jnp.ndarray, *, cfg, rows: int = DEFAULT_ROWS,
-                        interpret: bool = True):
+                        interpret: bool):
     """x2d: [R_total, 128] with R_total % rows == 0."""
     import numpy as np
 

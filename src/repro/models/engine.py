@@ -26,11 +26,11 @@ the engine ever moves a dequantized plane.
 
 Bit-identity: every slot is a batch-1 `QuantCache` stacked on a leading
 slot axis, and `generate_step` is `jax.vmap(serve_step)` over that axis
-with per-slot positions.  Slot computations are data-independent, and
-insertion decodes through the exact pack/unpack inverses, so each slot's
-logits are bit-identical to the single-request `serve_step` path at the
-same position (pinned by tests/test_engine.py, including through
-evict → insert churn and cross-host migration).
+with per-slot positions.  Insertion decodes through the exact pack/unpack
+inverses, so each slot's cache is bit-identical to the single-request
+path's, through evict → insert churn and cross-host migration.  Its
+logits match the batch-1 `serve_step` bit for bit only where the
+backend's matmuls do not depend on the batch size (DESIGN.md §10).
 
 Streaming migration (`stream_prefill`): on the prefill host each page is
 packed and handed to `Transport.send_pages` the moment it closes, while
@@ -387,15 +387,12 @@ class DecodeEngine:
 
 # --------------------------------------------------- streaming migration ---
 
-def _shard_map(f, mesh, in_specs, out_specs, axis: str):
-    """Version-compat shard_map (this repo supports pre-AxisType JAX)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names={axis},
-                             check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+def _block_on(a, rank: int):
+    """Rank `rank`'s block of an array stacked over a 1-D mesh axis, as
+    the array that already lives on that rank's device (no gather)."""
+    (shard,) = [s for s in a.addressable_shards
+                if s.index[0].start == rank]
+    return shard.data[0]
 
 
 def stream_prefill(cfg: ArchConfig, params, prompt, *, seq: int, mesh,
@@ -416,7 +413,7 @@ def stream_prefill(cfg: ArchConfig, params, prompt, *, seq: int, mesh,
     `stats` carry the per-wire byte ledger
     (`[(kind, page index, bytes), ...]`, accounted via
     `Transport.bytes_moved`)."""
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     tp = TRANSPORT if transport is None else transport
     kv_cfg = KVC.kv_quantizer_config() if kv_cfg is None else kv_cfg
@@ -424,12 +421,28 @@ def stream_prefill(cfg: ArchConfig, params, prompt, *, seq: int, mesh,
     m = int(prompt.shape[0])
     assert 0 < m < seq, (m, seq)
 
+    devs = list(mesh.devices.flat)
+    stacked = NamedSharding(mesh, P(axis))
+
+    def _on_src(a):
+        # `a` as rank src's block of a [p, ...] array, the other ranks
+        # holding zeros of their own: only the ppermute crosses the link
+        blocks = [jax.device_put(a, d)[None] if r == src
+                  else jnp.zeros((1,) + a.shape, a.dtype, device=d)
+                  for r, d in enumerate(devs)]
+        return jax.make_array_from_single_device_arrays(
+            (len(devs),) + a.shape, stacked, blocks)
+
     def _send(wire):
-        moved = tp.send_pages(wire, src, dst, axis)
+        moved = tp.send_pages(jax.tree.map(lambda a: a[0], wire), src, dst,
+                              axis)
         return jax.tree.map(lambda a: a[None], moved)
 
-    send = jax.jit(_shard_map(_send, mesh, P(), P(axis), axis))
-    take = lambda out: jax.tree.map(lambda a: a[dst], out)
+    send_blocks = jax.jit(jax.shard_map(_send, mesh=mesh, in_specs=P(axis),
+                                        out_specs=P(axis), axis_names={axis},
+                                        check_vma=False))
+    send = lambda wire: send_blocks(jax.tree.map(_on_src, wire))
+    take = lambda out: jax.tree.map(lambda a: _block_on(a, dst), out)
 
     step = jax.jit(lambda p, c, t, i: S.serve_step(cfg, p, c, t, i, None,
                                                    kv_cfg))

@@ -272,8 +272,10 @@ def _hybrid_stack(cfg: ArchConfig, params, x, positions, mesh, remat=True,
 
 def forward(cfg: ArchConfig, params, tokens, mesh=None, remat=True,
             moe_data_axes=None):
-    """tokens: int32 [B, S] -> logits [B, S, V] (bf16), aux loss."""
-    x = params["emb"][tokens].astype(DTYPE)
+    """tokens: int32 [B, S] -> logits [B, S, V], aux loss.  Activations
+    and logits take the embedding's dtype (bf16 as specified), so an f32
+    reference is this same code over an f32 embedding."""
+    x = params["emb"][tokens]
     positions = jnp.arange(tokens.shape[1])[None, :]
     if cfg.family == "hybrid":
         x, aux = _hybrid_stack(cfg, params, x, positions, mesh, remat,
@@ -283,7 +285,7 @@ def forward(cfg: ArchConfig, params, tokens, mesh=None, remat=True,
                                      moe_data_axes)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     ctx = L.ShardCtx(mesh, dp=moe_data_axes)
-    logits = ctx(x @ params["emb"].T.astype(DTYPE), 'dp', None, 'model')
+    logits = ctx(x @ params["emb"].T, 'dp', None, 'model')
     return logits, aux
 
 

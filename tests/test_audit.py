@@ -295,8 +295,6 @@ DROP_SCRIPT = textwrap.dedent("""
     from repro.compression.grads import (GradCompressionConfig,
                                          compress_shard, compressed_mean)
     from repro.core.transport import Transport
-    from tests.conftest import shard_map_compat as smap
-
     mesh = jax.make_mesh((2,), ("pod",))
     cfg = GradCompressionConfig(eb_rel=2.0 ** -6, bin_bits=16)
     n = 8192
@@ -317,7 +315,9 @@ DROP_SCRIPT = textwrap.dedent("""
             m, r = compressed_mean(gs.reshape(-1), cfg, "pod",
                                    transport=tp, integrity="drop")
             return m, r
-        return jax.jit(smap(body, mesh, P("pod"), (P(), P("pod"))))(g)
+        return jax.jit(jax.shard_map(
+            body, mesh=mesh, in_specs=P("pod"), out_specs=(P(), P("pod")),
+            axis_names={"pod"}, check_vma=False))(g)
 
     mean_clean, _ = run(tp_clean)
 
@@ -521,23 +521,12 @@ RING_INTEGRITY_SCRIPT = textwrap.dedent("""
     from repro.core.transport import TRANSPORT, Transport
     from repro.runtime.guard import FaultPlan
 
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((2,), ("pod",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    else:
-        mesh = jax.make_mesh((2,), ("pod",))
-    if hasattr(jax, "shard_map"):
-        def smap(f):
-            return jax.shard_map(f, mesh=mesh, in_specs=P("pod", None),
-                                 out_specs=(P("pod", None), P("pod")),
-                                 axis_names={"pod"}, check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def smap(f):
-            return _shard_map(f, mesh=mesh, in_specs=P("pod", None),
-                              out_specs=(P("pod", None), P("pod")),
-                              check_rep=False)
+    mesh = jax.make_mesh((2,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
+    def smap(f):
+        return jax.shard_map(f, mesh=mesh, in_specs=P("pod", None),
+                             out_specs=(P("pod"), P("pod")),
+                             axis_names={"pod"}, check_vma=False)
 
     # bin_bits=16 keeps the data outlier-free (range ~ +-5 >> the 1e-2
     # values) so the §8 ring genuinely fires — with outliers the compat

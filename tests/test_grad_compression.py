@@ -24,27 +24,13 @@ SCRIPT = textwrap.dedent("""
                                          compressed_mean,
                                          compressed_mean_tree)
 
-    # Version compat: jax.sharding.AxisType and the public jax.shard_map
-    # (with axis_names/check_vma) only exist on newer JAX.  Older releases
-    # get an explicit-Mesh + full-manual jax.experimental shard_map (the
-    # unused data/model axes are simply manual-and-idle there).
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
-    else:
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
 
-    if hasattr(jax, "shard_map"):
-        def smap(f, in_specs, out_specs):
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, axis_names={"pod"},
-                                 check_vma=False)
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def smap(f, in_specs, out_specs):
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+    def smap(f, in_specs, out_specs):
+        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, axis_names={"pod"},
+                             check_vma=False)
 
     cfg = GradCompressionConfig(eb_rel=2.0 ** -8, bin_bits=8,
                                 outlier_cap_frac=1 / 16)

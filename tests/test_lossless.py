@@ -355,7 +355,6 @@ def test_compressed_mean_lossless_stage_transparent(stage):
     the same shard_map collective."""
     from jax.sharding import PartitionSpec as P
 
-    from conftest import shard_map_compat
     from repro.compression.grads import compressed_mean
 
     n = 8192
@@ -365,8 +364,9 @@ def test_compressed_mean_lossless_stage_transparent(stage):
     mesh = jax.make_mesh((1,), ("pod",))
 
     def run(cfg):
-        mapped = shard_map_compat(lambda x: compressed_mean(x, cfg, "pod"),
-                                  mesh, P(), (P(), P()))
+        mapped = jax.shard_map(lambda x: compressed_mean(x, cfg, "pod"),
+                               mesh=mesh, in_specs=P(), out_specs=(P(), P()),
+                               axis_names={"pod"}, check_vma=False)
         return jax.jit(mapped)(jnp.asarray(g))
 
     base_cfg = GradCompressionConfig(eb_rel=2.0 ** -6, bin_bits=8,

@@ -215,9 +215,9 @@ def test_compressed_mean_outlier_at_last_index():
     cfg = GradCompressionConfig(eb_rel=2.0 ** -6, bin_bits=8,
                                 outlier_cap_frac=1 / 64)   # cap 64 >> 1
     mesh = jax.make_mesh((1,), ("pod",))
-    from conftest import shard_map_compat
-    mapped = shard_map_compat(lambda x: compressed_mean(x, cfg, "pod"),
-                              mesh, P(), (P(), P()))
+    mapped = jax.shard_map(lambda x: compressed_mean(x, cfg, "pod"),
+                           mesh=mesh, in_specs=P(), out_specs=(P(), P()),
+                           axis_names={"pod"}, check_vma=False)
     mean, resid = jax.jit(mapped)(jnp.asarray(g))
     mean = np.asarray(mean)
     assert mean[-1] == g[-1], (mean[-1], "outlier at last index not exact")
