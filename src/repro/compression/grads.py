@@ -49,6 +49,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import select as SEL
 from repro.core.pipeline import (Encoded, Pipeline, PackStage, QuantStage,
                                  parse_pipeline)
@@ -208,12 +209,19 @@ def compress_shard(g: jnp.ndarray, cfg: GradCompressionConfig,
     go on the wire.  `integrity=True` attaches the §12 wire checksum
     (an extra aux plane — the transmitted planes are unchanged)."""
     pipe = cfg.pipe()
-    flat = g.reshape(-1).astype(jnp.float32)
-    rms = jnp.sqrt(jnp.mean(flat * flat))
-    eb = jnp.asarray(cfg.eb_rel, jnp.float32) * rms
-    enc, q = pipe.encode(flat, eb=eb, return_quantized=True,
-                         integrity=integrity)
+    with obs.scope("grads.compress"):
+        flat = g.reshape(-1).astype(jnp.float32)
+        rms = jnp.sqrt(jnp.mean(flat * flat))
+        eb = jnp.asarray(cfg.eb_rel, jnp.float32) * rms
+        enc, q = pipe.encode(flat, eb=eb, return_quantized=True,
+                             integrity=integrity)
     return CompressedShard(enc, pipe, flat.size), q
+
+
+def _psum_fallback(flat, axis):
+    """The overflow branch of the compressed mean: the raw f32 sum."""
+    with obs.scope("transport.psum_fallback"):
+        return jax.lax.psum(flat, axis)
 
 
 def compressed_mean(g: jnp.ndarray, cfg: GradCompressionConfig, axis: str,
@@ -258,12 +266,12 @@ def compressed_mean(g: jnp.ndarray, cfg: GradCompressionConfig, axis: str,
 
         mean = jax.lax.cond(
             any_overflow,
-            lambda _: jax.lax.psum(flat, axis) / p,
+            lambda _: _psum_fallback(flat, axis) / p,
             _verified_mean, None)
     else:
         summed = jax.lax.cond(
             any_overflow,
-            lambda _: jax.lax.psum(flat, axis),
+            lambda _: _psum_fallback(flat, axis),
             lambda _: tp.reduce_sum(shard.enc, shard.pipe, flat.size, axis),
             None)
         mean = summed / p

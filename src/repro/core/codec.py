@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import quantizer as q
 from .bitops import bits_to_float, float_to_bits
 from .config import QuantizerConfig
@@ -337,27 +338,38 @@ def encode_packed(x: jnp.ndarray, cfg: QuantizerConfig, eb=None, *,
     (core.predict / DESIGN.md §9).  It must be inverted by the matching
     `bin_untransform` in decode_packed; the returned Quantized keeps the
     UNtransformed bins so residual bookkeeping stays in the value domain."""
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    k = cfg.outlier_cap(n)
-    if cfg.mode == "abs":
-        qt = q.quantize_abs(flat, cfg, eb=eb)
-    elif cfg.mode == "rel":
-        qt = q.quantize_rel(flat, cfg)
-    else:
-        qt, eb = q.quantize_noa(flat, cfg)
-    n_out = jnp.sum(qt.outlier).astype(jnp.int32)
-    (idx,) = jnp.nonzero(qt.outlier, size=k, fill_value=n)
-    safe_idx = jnp.minimum(idx, n - 1)
-    payload = jnp.where(idx < n, float_to_bits(flat)[safe_idx], 0)
-    bins = qt.bins if bin_transform is None else bin_transform(qt.bins)
-    words = pack_words(bins, cfg.bin_bits)
-    sign_words = None if qt.sign is None else pack_flags(qt.sign)
+    with obs.scope("codec.quantize_pack"):
+        flat = x.reshape(-1)
+        n = flat.shape[0]
+        k = cfg.outlier_cap(n)
+        if cfg.mode == "abs":
+            qt = q.quantize_abs(flat, cfg, eb=eb)
+        elif cfg.mode == "rel":
+            qt = q.quantize_rel(flat, cfg)
+        else:
+            qt, eb = q.quantize_noa(flat, cfg)
+        bins = qt.bins if bin_transform is None else bin_transform(qt.bins)
+        words = pack_words(bins, cfg.bin_bits)
+        sign_words = None if qt.sign is None else pack_flags(qt.sign)
+    n_out, idx, payload = compact_outliers(flat, qt.outlier, k)
     enc = EncodedPacked(words, idx.astype(jnp.int32),
                         payload.astype(jnp.uint32), n_out, n_out > k,
                         sign_words,
                         None if eb is None else jnp.asarray(eb, flat.dtype))
     return (enc, qt) if return_quantized else enc
+
+
+def compact_outliers(flat: jnp.ndarray, outlier: jnp.ndarray, k: int):
+    """The capped exact-outlier table of one encode: (count, the first k
+    outlier indices padded with n, their raw bits).  Shared by the
+    reference and both fused encoders."""
+    n = flat.shape[0]
+    with obs.scope("codec.compact"):
+        n_out = jnp.sum(outlier).astype(jnp.int32)
+        (idx,) = jnp.nonzero(outlier, size=k, fill_value=n)
+        safe_idx = jnp.minimum(idx, n - 1)
+        payload = jnp.where(idx < n, float_to_bits(flat)[safe_idx], 0)
+    return n_out, idx, payload
 
 
 def decode_packed(enc: EncodedPacked, cfg: QuantizerConfig, n: int | None = None,
@@ -370,6 +382,11 @@ def decode_packed(enc: EncodedPacked, cfg: QuantizerConfig, n: int | None = None
         if shape is None:
             raise ValueError("decode_packed needs n or shape")
         n = int(np.prod(shape))
+    with obs.scope("codec.dequantize"):
+        return _decode_packed(enc, cfg, n, shape, dtype, bin_untransform)
+
+
+def _decode_packed(enc, cfg, n, shape, dtype, bin_untransform):
     dt = jnp.dtype(dtype or cfg.dtype)
     bins = unpack_words(enc.words, n, cfg.bin_bits)
     if bin_untransform is not None:
@@ -501,12 +518,13 @@ def compact_chunks(sel: jnp.ndarray, lens: jnp.ndarray):
     narrow chunk coder and the `ent` entropy stage."""
     n_chunks = sel.shape[0]
     cap = n_chunks * LC_CHUNK
-    ends = jnp.cumsum(lens)
-    offs = ends - lens
-    slot = jnp.arange(LC_CHUNK, dtype=jnp.int32)[None, :]
-    dest = jnp.where(slot < lens[:, None], offs[:, None] + slot, cap)
-    payload = jnp.zeros((cap,), jnp.uint32).at[dest.reshape(-1)].set(
-        sel.reshape(-1), mode="drop")
+    with obs.scope("codec.compact"):
+        ends = jnp.cumsum(lens)
+        offs = ends - lens
+        slot = jnp.arange(LC_CHUNK, dtype=jnp.int32)[None, :]
+        dest = jnp.where(slot < lens[:, None], offs[:, None] + slot, cap)
+        payload = jnp.zeros((cap,), jnp.uint32).at[dest.reshape(-1)].set(
+            sel.reshape(-1), mode="drop")
     return payload, ends[-1].astype(jnp.int32)
 
 

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import audit as A
 from . import codec as C
 from . import predict as P
@@ -325,10 +326,12 @@ def encode_word_stages(stages, words, n_words: int):
     payload, transmitted_len)."""
     headers, cur, cur_n = [], words, n_words
     plen = jnp.int32(n_words)
-    for st in stages:
-        hdr, cur, plen = st.encode_words(cur, cur_n)
-        headers.append(hdr)
-        cur_n = st.capacity_words(cur_n)
+    with obs.scope("codec.word_stages"):
+        for st in stages:
+            with obs.scope(st.spec()):
+                hdr, cur, plen = st.encode_words(cur, cur_n)
+            headers.append(hdr)
+            cur_n = st.capacity_words(cur_n)
     return tuple(headers), cur, plen
 
 
@@ -337,8 +340,11 @@ def decode_word_stages(stages, headers, payload, n_words: int):
     """Exact inverse of encode_word_stages."""
     sizes = word_stage_sizes(stages, n_words)
     cur = payload
-    for st, hdr, n_in in reversed(list(zip(stages, headers, sizes[:-1]))):
-        cur = st.decode_words(hdr, cur, n_in)
+    with obs.scope("codec.word_stages"):
+        for st, hdr, n_in in reversed(list(zip(stages, headers,
+                                                sizes[:-1]))):
+            with obs.scope(st.spec()):
+                cur = st.decode_words(hdr, cur, n_in)
     return cur
 
 
@@ -462,40 +468,41 @@ class Pipeline:
         the covered planes are bit-identical across backends).  Returns
         enc | (enc, qt) | (enc, report) | (enc, qt, report)."""
         n = int(np.prod(x.shape))
-        if pred_shape is None:
-            pred_shape = tuple(x.shape)
-        use_k = (self._auto_kernels() if kernels is None else kernels)
-        if use_k and not return_quantized and not verify:
-            target = self.kernel_dispatch()
-            if target == "repro.kernels.pack.encode_packed":
-                from repro.kernels import pack as _kp      # lazy: circular
-                ep = _kp.encode_packed(x, self.qcfg(), eb,
-                                       interpret=interpret)
-                enc = self._wrap_packed(ep, n)
-                return A.attach_checksum(enc) if integrity else enc
-            if target == "repro.kernels.lossless.encode_packed_lc":
-                from repro.kernels import lossless as _kl
-                lc = _kl.encode_packed_lc(x, self.qcfg(), eb,
-                                          stage=self.stages[0].mode,
-                                          interpret=interpret)
-                enc = Encoded(lc.payload, lc.payload_len,
-                              (lc.header_words,), lc.out_idx,
-                              lc.out_payload, lc.n_outliers, lc.overflow,
-                              lc.sign_words, lc.eb)
-                return A.attach_checksum(enc) if integrity else enc
-        ep, qt = C.encode_packed(x, self.qcfg(), eb, return_quantized=True,
-                                 bin_transform=self._bin_transform(
-                                     pred_shape, n))
-        enc = self._wrap_packed(ep, n)
-        if integrity:
-            enc = A.attach_checksum(enc)
-        if verify:
-            report = A.audit_report(
-                x, qt, self.qcfg(),
-                eb=enc.eb if enc.eb is not None else eb,
-                overflow=enc.overflow, n_outliers=enc.n_outliers)
-            return (enc, qt, report) if return_quantized else (enc, report)
-        return (enc, qt) if return_quantized else enc
+        with obs.span("codec.encode", x, chain=self.spec(), n=n):
+            if pred_shape is None:
+                pred_shape = tuple(x.shape)
+            use_k = (self._auto_kernels() if kernels is None else kernels)
+            if use_k and not return_quantized and not verify:
+                target = self.kernel_dispatch()
+                if target == "repro.kernels.pack.encode_packed":
+                    from repro.kernels import pack as _kp      # lazy: circular
+                    ep = _kp.encode_packed(x, self.qcfg(), eb,
+                                           interpret=interpret)
+                    enc = self._wrap_packed(ep, n)
+                    return A.attach_checksum(enc) if integrity else enc
+                if target == "repro.kernels.lossless.encode_packed_lc":
+                    from repro.kernels import lossless as _kl
+                    lc = _kl.encode_packed_lc(x, self.qcfg(), eb,
+                                              stage=self.stages[0].mode,
+                                              interpret=interpret)
+                    enc = Encoded(lc.payload, lc.payload_len,
+                                  (lc.header_words,), lc.out_idx,
+                                  lc.out_payload, lc.n_outliers, lc.overflow,
+                                  lc.sign_words, lc.eb)
+                    return A.attach_checksum(enc) if integrity else enc
+            ep, qt = C.encode_packed(x, self.qcfg(), eb, return_quantized=True,
+                                     bin_transform=self._bin_transform(
+                                         pred_shape, n))
+            enc = self._wrap_packed(ep, n)
+            if integrity:
+                enc = A.attach_checksum(enc)
+            if verify:
+                report = A.audit_report(
+                    x, qt, self.qcfg(),
+                    eb=enc.eb if enc.eb is not None else eb,
+                    overflow=enc.overflow, n_outliers=enc.n_outliers)
+                return (enc, qt, report) if return_quantized else (enc, report)
+            return (enc, qt) if return_quantized else enc
 
     # --- decode -----------------------------------------------------------
 
@@ -521,26 +528,28 @@ class Pipeline:
             n = int(np.prod(shape))
         if pred_shape is None and shape is not None:
             pred_shape = tuple(shape)
-        A.check_payload_len(enc.payload_len, enc.payload.shape[0],
-                            what=f"Encoded[{self.spec()}]")
-        if verify:
-            ok = A.verify_wire(enc)
-            if not isinstance(ok, jax.core.Tracer) and not bool(ok):
-                raise A.WireIntegrityError(
-                    f"Encoded[{self.spec()}]: checksum mismatch on decode")
-        words = self.decode_words(enc.headers, enc.payload, self.n_words(n))
-        ep = C.EncodedPacked(words, enc.out_idx, enc.out_payload,
-                             enc.n_outliers, enc.overflow, enc.sign_words,
-                             enc.eb)
-        use_k = (self._auto_kernels() if kernels is None else kernels)
-        if use_k and not self.pred:
-            from repro.kernels import pack as _kp          # lazy: circular
-            return _kp.decode_packed(ep, self.qcfg(), n=n, shape=shape,
-                                     dtype=dtype, interpret=interpret)
-        return C.decode_packed(ep, self.qcfg(), n=n, shape=shape,
-                               dtype=dtype,
-                               bin_untransform=self._bin_untransform(
-                                   pred_shape, n))
+        with obs.span("codec.decode", enc.payload, chain=self.spec(), n=n):
+            with obs.span("codec.decode.check_len", enc.payload_len):
+                A.check_payload_len(enc.payload_len, enc.payload.shape[0],
+                                    what=f"Encoded[{self.spec()}]")
+            if verify:
+                ok = A.verify_wire(enc)
+                if not isinstance(ok, jax.core.Tracer) and not bool(ok):
+                    raise A.WireIntegrityError(
+                        f"Encoded[{self.spec()}]: checksum mismatch on decode")
+            words = self.decode_words(enc.headers, enc.payload, self.n_words(n))
+            ep = C.EncodedPacked(words, enc.out_idx, enc.out_payload,
+                                 enc.n_outliers, enc.overflow, enc.sign_words,
+                                 enc.eb)
+            use_k = (self._auto_kernels() if kernels is None else kernels)
+            if use_k and not self.pred:
+                from repro.kernels import pack as _kp          # lazy: circular
+                return _kp.decode_packed(ep, self.qcfg(), n=n, shape=shape,
+                                         dtype=dtype, interpret=interpret)
+            return C.decode_packed(ep, self.qcfg(), n=n, shape=shape,
+                                   dtype=dtype,
+                                   bin_untransform=self._bin_untransform(
+                                       pred_shape, n))
 
     def roundtrip(self, x, eb=None, **kw):
         return self.decode(self.encode(x, eb, **kw), shape=x.shape, **kw)

@@ -54,6 +54,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from . import audit as A
 from . import codec as C
 from .pipeline import Encoded, Pipeline
@@ -360,9 +361,11 @@ class Transport:
         # exact inverse per pod, sum.  Ops and order match the
         # pre-transport compressed_mean gather/dequant exactly (pinned by
         # tests/test_transport.py), so the refactor cannot move a bit.
-        enc_all = self.all_gather(enc, axis)
-        dec = jax.vmap(lambda e: pipe.decode(e, n=n, kernels=False))(enc_all)
-        return jnp.sum(dec, axis=0)
+        with obs.scope("transport.gather"):
+            enc_all = self.all_gather(enc, axis)
+            dec = jax.vmap(lambda e: pipe.decode(e, n=n, kernels=False))(
+                enc_all)
+            return jnp.sum(dec, axis=0)
 
     def _ring_sum(self, enc, qc, n, axis, p: int):
         # packed-domain ring: each hop moves the §4 word plane (bin_bits
@@ -370,12 +373,13 @@ class Transport:
         # dequantize ONCE.  Valid only under the §8 compatibility rule —
         # reduce_sum guards it; do not call directly without those checks.
         perm = [(i, (i + 1) % p) for i in range(p)]
-        total = C.unpack_words(enc.payload, n, qc.bin_bits)
-        cur = enc.payload
-        for _ in range(p - 1):
-            cur = jax.lax.ppermute(cur, axis, perm)
-            total = total + C.unpack_words(cur, n, qc.bin_bits)
-        return dequantize_abs(total, qc, eb=enc.eb, dtype=jnp.float32)
+        with obs.scope("transport.ring"):
+            total = C.unpack_words(enc.payload, n, qc.bin_bits)
+            cur = enc.payload
+            for _ in range(p - 1):
+                cur = jax.lax.ppermute(cur, axis, perm)
+                total = total + C.unpack_words(cur, n, qc.bin_bits)
+            return dequantize_abs(total, qc, eb=enc.eb, dtype=jnp.float32)
 
     def _reduce_checked(self, enc, pipe, n, axis, integrity: str):
         # the §12 verified reduce: -> (masked sum, per-rank valid count).
@@ -394,12 +398,14 @@ class Transport:
     def _gather_sum_checked(self, enc, pipe, n, axis):
         # gather fallback of the verified reduce: per-shard whole-wire
         # checksum verdicts mask the per-pod decodes out of the sum.
-        enc_all, ok = self.all_gather(enc, axis, verify="mask")
-        dec = jax.vmap(lambda e: pipe.decode(e, n=n, kernels=False))(enc_all)
-        mask = ok.reshape((-1,) + (1,) * (dec.ndim - 1))
-        total = jnp.sum(jnp.where(mask, dec, jnp.zeros((), dec.dtype)),
-                        axis=0)
-        return total, jnp.sum(ok.astype(jnp.int32))
+        with obs.scope("transport.gather"):
+            enc_all, ok = self.all_gather(enc, axis, verify="mask")
+            dec = jax.vmap(lambda e: pipe.decode(e, n=n, kernels=False))(
+                enc_all)
+            mask = ok.reshape((-1,) + (1,) * (dec.ndim - 1))
+            total = jnp.sum(jnp.where(mask, dec, jnp.zeros((), dec.dtype)),
+                            axis=0)
+            return total, jnp.sum(ok.astype(jnp.int32))
 
     def _ring_sum_checked(self, enc, qc, n, axis, p: int):
         # verified ring (§12): the hop wire is (payload, owner digest) —
@@ -410,20 +416,21 @@ class Transport:
         # intermediate hops).  Failed hops are masked out of the int32
         # bin accumulation and the valid count; own bins always count.
         perm = [(i, (i + 1) % p) for i in range(p)]
-        total = C.unpack_words(enc.payload, n, qc.bin_bits)
-        cur, cs = enc.payload, A.plane_checksum(enc.payload)
-        n_valid = jnp.int32(1)
-        for _ in range(p - 1):
-            cur = jax.lax.ppermute(cur, axis, perm)
-            cs = jax.lax.ppermute(cs, axis, perm)
-            if self.fault is not None:     # §12 hook: corrupt the hop pair
-                cur, cs = self.fault((cur, cs))
-            ok = A.plane_checksum(cur) == cs
-            bins = C.unpack_words(cur, n, qc.bin_bits)
-            total = total + jnp.where(ok, bins, jnp.zeros((), bins.dtype))
-            n_valid = n_valid + ok.astype(jnp.int32)
-        return (dequantize_abs(total, qc, eb=enc.eb, dtype=jnp.float32),
-                n_valid)
+        with obs.scope("transport.ring"):
+            total = C.unpack_words(enc.payload, n, qc.bin_bits)
+            cur, cs = enc.payload, A.plane_checksum(enc.payload)
+            n_valid = jnp.int32(1)
+            for _ in range(p - 1):
+                cur = jax.lax.ppermute(cur, axis, perm)
+                cs = jax.lax.ppermute(cs, axis, perm)
+                if self.fault is not None:     # §12 hook: corrupt the hop pair
+                    cur, cs = self.fault((cur, cs))
+                ok = A.plane_checksum(cur) == cs
+                bins = C.unpack_words(cur, n, qc.bin_bits)
+                total = total + jnp.where(ok, bins, jnp.zeros((), bins.dtype))
+                n_valid = n_valid + ok.astype(jnp.int32)
+            return (dequantize_abs(total, qc, eb=enc.eb, dtype=jnp.float32),
+                    n_valid)
 
     # --- accounting -------------------------------------------------------
 

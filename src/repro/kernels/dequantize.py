@@ -54,6 +54,7 @@ def dequantize_abs_pallas(bins2d, payload2d, outlier2d, eb, *, dtype,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((r_total, LANES), dtype),
         interpret=interpret,
+        name="dequantize_abs",
     )(bins2d, payload2d, outlier2d, eb)
 
 
@@ -72,4 +73,5 @@ def dequantize_rel_pallas(bins2d, payload2d, outlier2d, sign2d, *, cfg,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((r_total, LANES), dtype),
         interpret=interpret,
+        name="dequantize_rel",
     )(bins2d, payload2d, outlier2d, sign2d)

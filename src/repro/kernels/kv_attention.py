@@ -130,5 +130,6 @@ def kv_decode_attention(q, kq, vq, lengths, *, page=128, cap=8,
             pltpu.VMEM((hg, 1), jnp.float32),    # running denom l
         ],
         interpret=interpret,
+        name="kv_decode_attention",
     )(lengths, q, kq.bins, kq.eb2, kq.out_idx, kq.out_val,
       vq.bins, vq.eb2, vq.out_idx, vq.out_val)

@@ -33,9 +33,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs
 from repro.core import QuantizerConfig
 from repro.core import codec as C
-from repro.core.bitops import float_to_bits
 
 from .pack import (LANES, _abs_quantize_block, _eb_row, _narrow_mask,
                    _pack_block, _rel_quantize_block, _tile_words,
@@ -171,6 +171,7 @@ def chunk_select_pallas(words2d, stage, *, wrows=DEFAULT_ROWS,
             jax.ShapeDtypeStruct((w_total // CHUNK_ROWS, LANES), jnp.uint32),
         ],
         interpret=interpret,
+        name="lc_chunk_select",
     )(words2d)
 
 
@@ -189,6 +190,7 @@ def chunk_expand_pallas(padded2d, codes2d, *, wrows=DEFAULT_ROWS,
         out_specs=pl.BlockSpec((wrows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((w_total, LANES), jnp.uint32),
         interpret=interpret,
+        name="lc_chunk_expand",
     )(padded2d, codes2d)
 
 
@@ -198,11 +200,12 @@ def _finish_encode(sel2d, codes2d, n_words):
     reference — pad chunks beyond n_words are all-zero by the zero-pad
     invariant, so truncation is exact."""
     n_chunks = C.lc_chunk_count(n_words)
-    codes = codes2d.reshape(-1, LANES)[:n_chunks, 0].astype(jnp.int32)
-    sel = sel2d.reshape(-1)[:n_chunks * C.LC_CHUNK].reshape(
-        n_chunks, C.LC_CHUNK)
-    payload, plen = C.lc_compact_payload(sel, codes)
-    return C.pack_words(codes, 2), payload, plen
+    with obs.scope("codec.compact"):
+        codes = codes2d.reshape(-1, LANES)[:n_chunks, 0].astype(jnp.int32)
+        sel = sel2d.reshape(-1)[:n_chunks * C.LC_CHUNK].reshape(
+            n_chunks, C.LC_CHUNK)
+        payload, plen = C.lc_compact_payload(sel, codes)
+        return C.pack_words(codes, 2), payload, plen
 
 
 # ------------------------------------------------------ jit'd public API --
@@ -266,94 +269,94 @@ def encode_packed_lc(x, cfg: QuantizerConfig, eb=None, stage="narrow", *,
     import numpy as np
 
     interpret = _use_interpret() if interpret is None else interpret
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    k = cfg.outlier_cap(n)
     vpw = 32 // cfg.bin_bits
     assert rows % 32 == 0 and (rows // vpw) % CHUNK_ROWS == 0, rows
-    if cfg.mode == "noa":
-        finite = jnp.isfinite(flat)
-        big = jnp.asarray(np.finfo(flat.dtype).max, flat.dtype)
-        hi = jnp.max(jnp.where(finite, flat, -big))
-        lo = jnp.min(jnp.where(finite, flat, big))
-        eb = jnp.asarray(cfg.error_bound, flat.dtype) * (hi - lo)
+    with obs.scope("codec.quantize_pack"):
+        flat = x.reshape(-1)
+        n = flat.shape[0]
+        k = cfg.outlier_cap(n)
+        if cfg.mode == "noa":
+            finite = jnp.isfinite(flat)
+            big = jnp.asarray(np.finfo(flat.dtype).max, flat.dtype)
+            hi = jnp.max(jnp.where(finite, flat, -big))
+            lo = jnp.min(jnp.where(finite, flat, big))
+            eb = jnp.asarray(cfg.error_bound, flat.dtype) * (hi - lo)
 
-    block = rows * LANES
-    pad = (-n) % block
-    x2d = jnp.pad(flat, (0, pad)).reshape(-1, LANES)
-    r_total = x2d.shape[0]
-    grid = (r_total // rows,)
-    sign_words = None
-    if cfg.mode == "rel":
-        eb_, log_step, inv_log_step = cfg.rel_constants()
-        mb, emask, bias = ((23, 0xFF, 127) if x2d.dtype == jnp.float32
-                           else (52, 0x7FF, 1023))
-        body = functools.partial(
-            _rel_pack_lc_kernel, maxbin=cfg.maxbin, tighten=cfg.tighten,
-            eb=float(eb_), log_step=float(log_step),
-            inv_log_step=float(inv_log_step),
-            screen=float(cfg.rel_screen_threshold()),
-            tiny=float(np.finfo(x2d.dtype).tiny), mb=mb, emask=emask,
-            bias=bias, bin_bits=cfg.bin_bits, stage=stage)
-        words2d, out2d, sw2d, sel2d, codes2d = pl.pallas_call(
-            body,
-            grid=grid,
-            in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
-            out_specs=[
-                pl.BlockSpec((rows // vpw, LANES), lambda i: (i, 0)),
-                pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-                pl.BlockSpec((rows // 32, LANES), lambda i: (i, 0)),
-                pl.BlockSpec((rows // vpw, LANES), lambda i: (i, 0)),
-                pl.BlockSpec((rows // vpw // CHUNK_ROWS, LANES),
-                             lambda i: (i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((r_total // vpw, LANES), jnp.uint32),
-                jax.ShapeDtypeStruct((r_total, LANES), jnp.bool_),
-                jax.ShapeDtypeStruct((r_total // 32, LANES), jnp.uint32),
-                jax.ShapeDtypeStruct((r_total // vpw, LANES), jnp.uint32),
-                jax.ShapeDtypeStruct((r_total // vpw // CHUNK_ROWS, LANES),
-                                     jnp.uint32),
-            ],
-            interpret=interpret,
-        )(x2d)
-        sign_words = sw2d.reshape(-1)[:C.packed_word_count(n, 1)]
-    else:
-        eb_arr = jnp.full((1, 1), cfg.error_bound if eb is None else eb,
-                          x2d.dtype)
-        body = functools.partial(_abs_pack_lc_kernel, maxbin=cfg.maxbin,
-                                 tighten=cfg.tighten, eb_floor=cfg.eb_floor,
-                                 bin_bits=cfg.bin_bits, stage=stage)
-        words2d, out2d, sel2d, codes2d = pl.pallas_call(
-            body,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-                pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((rows // vpw, LANES), lambda i: (i, 0)),
-                pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
-                pl.BlockSpec((rows // vpw, LANES), lambda i: (i, 0)),
-                pl.BlockSpec((rows // vpw // CHUNK_ROWS, LANES),
-                             lambda i: (i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((r_total // vpw, LANES), jnp.uint32),
-                jax.ShapeDtypeStruct((r_total, LANES), jnp.bool_),
-                jax.ShapeDtypeStruct((r_total // vpw, LANES), jnp.uint32),
-                jax.ShapeDtypeStruct((r_total // vpw // CHUNK_ROWS, LANES),
-                                     jnp.uint32),
-            ],
-            interpret=interpret,
-        )(x2d, eb_arr)
+        block = rows * LANES
+        pad = (-n) % block
+        x2d = jnp.pad(flat, (0, pad)).reshape(-1, LANES)
+        r_total = x2d.shape[0]
+        grid = (r_total // rows,)
+        sign_words = None
+        if cfg.mode == "rel":
+            eb_, log_step, inv_log_step = cfg.rel_constants()
+            mb, emask, bias = ((23, 0xFF, 127) if x2d.dtype == jnp.float32
+                               else (52, 0x7FF, 1023))
+            body = functools.partial(
+                _rel_pack_lc_kernel, maxbin=cfg.maxbin, tighten=cfg.tighten,
+                eb=float(eb_), log_step=float(log_step),
+                inv_log_step=float(inv_log_step),
+                screen=float(cfg.rel_screen_threshold()),
+                tiny=float(np.finfo(x2d.dtype).tiny), mb=mb, emask=emask,
+                bias=bias, bin_bits=cfg.bin_bits, stage=stage)
+            words2d, out2d, sw2d, sel2d, codes2d = pl.pallas_call(
+                body,
+                grid=grid,
+                in_specs=[pl.BlockSpec((rows, LANES), lambda i: (i, 0))],
+                out_specs=[
+                    pl.BlockSpec((rows // vpw, LANES), lambda i: (i, 0)),
+                    pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+                    pl.BlockSpec((rows // 32, LANES), lambda i: (i, 0)),
+                    pl.BlockSpec((rows // vpw, LANES), lambda i: (i, 0)),
+                    pl.BlockSpec((rows // vpw // CHUNK_ROWS, LANES),
+                                 lambda i: (i, 0)),
+                ],
+                out_shape=[
+                    jax.ShapeDtypeStruct((r_total // vpw, LANES), jnp.uint32),
+                    jax.ShapeDtypeStruct((r_total, LANES), jnp.bool_),
+                    jax.ShapeDtypeStruct((r_total // 32, LANES), jnp.uint32),
+                    jax.ShapeDtypeStruct((r_total // vpw, LANES), jnp.uint32),
+                    jax.ShapeDtypeStruct((r_total // vpw // CHUNK_ROWS, LANES),
+                                         jnp.uint32),
+                ],
+                interpret=interpret,
+                name="quantize_pack_lc_rel",
+            )(x2d)
+            sign_words = sw2d.reshape(-1)[:C.packed_word_count(n, 1)]
+        else:
+            eb_arr = jnp.full((1, 1), cfg.error_bound if eb is None else eb,
+                              x2d.dtype)
+            body = functools.partial(_abs_pack_lc_kernel, maxbin=cfg.maxbin,
+                                     tighten=cfg.tighten, eb_floor=cfg.eb_floor,
+                                     bin_bits=cfg.bin_bits, stage=stage)
+            words2d, out2d, sel2d, codes2d = pl.pallas_call(
+                body,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+                    pl.BlockSpec((1, 1), lambda i: (0, 0)),
+                ],
+                out_specs=[
+                    pl.BlockSpec((rows // vpw, LANES), lambda i: (i, 0)),
+                    pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+                    pl.BlockSpec((rows // vpw, LANES), lambda i: (i, 0)),
+                    pl.BlockSpec((rows // vpw // CHUNK_ROWS, LANES),
+                                 lambda i: (i, 0)),
+                ],
+                out_shape=[
+                    jax.ShapeDtypeStruct((r_total // vpw, LANES), jnp.uint32),
+                    jax.ShapeDtypeStruct((r_total, LANES), jnp.bool_),
+                    jax.ShapeDtypeStruct((r_total // vpw, LANES), jnp.uint32),
+                    jax.ShapeDtypeStruct((r_total // vpw // CHUNK_ROWS, LANES),
+                                         jnp.uint32),
+                ],
+                interpret=interpret,
+                name="quantize_pack_lc_abs",
+            )(x2d, eb_arr)
 
     n_words = C.packed_word_count(n, cfg.bin_bits)
-    outlier = out2d.reshape(-1)[:n]
-    n_out = jnp.sum(outlier).astype(jnp.int32)
-    (idx,) = jnp.nonzero(outlier, size=k, fill_value=n)
-    safe_idx = jnp.minimum(idx, n - 1)
-    payload_out = jnp.where(idx < n, float_to_bits(flat)[safe_idx], 0)
+    n_out, idx, payload_out = C.compact_outliers(
+        flat, out2d.reshape(-1)[:n], k)
     hw, payload, plen = _finish_encode(sel2d, codes2d, n_words)
     return C.EncodedLC(hw, payload, plen, idx.astype(jnp.int32),
                        payload_out.astype(jnp.uint32), n_out, n_out > k,

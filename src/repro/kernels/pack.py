@@ -43,9 +43,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro import obs
 from repro.core import QuantizerConfig
 from repro.core import codec as C
-from repro.core.bitops import float_to_bits
 from repro.core.quantizer import Quantized
 
 from .quantize_abs import DEFAULT_ROWS, LANES
@@ -239,6 +239,7 @@ def quantize_pack_abs_pallas(x2d, eb, *, maxbin, tighten, eb_floor, bin_bits,
             jax.ShapeDtypeStruct((r_total, LANES), jnp.bool_),
         ],
         interpret=interpret,
+        name="quantize_pack_abs",
     )(x2d, eb)
 
 
@@ -273,6 +274,7 @@ def quantize_pack_rel_pallas(x2d, *, cfg, rows=DEFAULT_ROWS, interpret):
             jax.ShapeDtypeStruct((r_total // 32, LANES), jnp.uint32),
         ],
         interpret=interpret,
+        name="quantize_pack_rel",
     )(x2d)
 
 
@@ -297,6 +299,7 @@ def unpack_dequant_abs_pallas(words2d, eb, *, dtype, eb_floor, bin_bits,
         out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((w_total * vpw, LANES), dtype),
         interpret=interpret,
+        name="unpack_dequant_abs",
     )(words2d, eb)
 
 
@@ -320,6 +323,7 @@ def unpack_dequant_rel_pallas(words2d, sign_words2d, *, cfg, dtype,
         out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((w_total * vpw, LANES), dtype),
         interpret=interpret,
+        name="unpack_dequant_rel",
     )(words2d, sign_words2d)
 
 
@@ -342,41 +346,39 @@ def encode_packed(x, cfg: QuantizerConfig, eb=None, *, rows=DEFAULT_ROWS,
                   interpret=None) -> C.EncodedPacked:
     """Fused-kernel twin of core.codec.encode_packed (bit-exact)."""
     interpret = _use_interpret() if interpret is None else interpret
-    flat = x.reshape(-1)
-    n = flat.shape[0]
-    k = cfg.outlier_cap(n)
-    if cfg.mode == "noa":
-        # NOA = ABS with eb from the global value range (needs the full
-        # tensor -> computed here, quantized by the ABS kernel)
-        finite = jnp.isfinite(flat)
-        import numpy as np
-        big = jnp.asarray(np.finfo(flat.dtype).max, flat.dtype)
-        hi = jnp.max(jnp.where(finite, flat, -big))
-        lo = jnp.min(jnp.where(finite, flat, big))
-        eb = jnp.asarray(cfg.error_bound, flat.dtype) * (hi - lo)
+    with obs.scope("codec.quantize_pack"):
+        flat = x.reshape(-1)
+        n = flat.shape[0]
+        k = cfg.outlier_cap(n)
+        if cfg.mode == "noa":
+            # NOA = ABS with eb from the global value range (needs the full
+            # tensor -> computed here, quantized by the ABS kernel)
+            finite = jnp.isfinite(flat)
+            import numpy as np
+            big = jnp.asarray(np.finfo(flat.dtype).max, flat.dtype)
+            hi = jnp.max(jnp.where(finite, flat, -big))
+            lo = jnp.min(jnp.where(finite, flat, big))
+            eb = jnp.asarray(cfg.error_bound, flat.dtype) * (hi - lo)
 
-    x2d, _ = _tile_zero(flat, rows)
-    sign_words = None
-    if cfg.mode == "rel":
-        words2d, out2d, sw2d = quantize_pack_rel_pallas(
-            x2d, cfg=cfg, rows=rows, interpret=interpret)
-        sign_words = sw2d.reshape(-1)[:C.packed_word_count(n, 1)]
-    else:
-        eb_arr = jnp.full((1, 1), cfg.error_bound if eb is None else eb,
-                          x2d.dtype)
-        words2d, out2d = quantize_pack_abs_pallas(
-            x2d, eb_arr, maxbin=cfg.maxbin, tighten=cfg.tighten,
-            eb_floor=cfg.eb_floor, bin_bits=cfg.bin_bits, rows=rows,
-            interpret=interpret)
-    # pad words beyond the reference tile count are all-zero (zero pad in,
-    # zero bins out) — truncate to the canonical wire length
-    words = words2d.reshape(-1)[:C.packed_word_count(n, cfg.bin_bits)]
-    outlier = out2d.reshape(-1)[:n]
+        x2d, _ = _tile_zero(flat, rows)
+        sign_words = None
+        if cfg.mode == "rel":
+            words2d, out2d, sw2d = quantize_pack_rel_pallas(
+                x2d, cfg=cfg, rows=rows, interpret=interpret)
+            sign_words = sw2d.reshape(-1)[:C.packed_word_count(n, 1)]
+        else:
+            eb_arr = jnp.full((1, 1), cfg.error_bound if eb is None else eb,
+                              x2d.dtype)
+            words2d, out2d = quantize_pack_abs_pallas(
+                x2d, eb_arr, maxbin=cfg.maxbin, tighten=cfg.tighten,
+                eb_floor=cfg.eb_floor, bin_bits=cfg.bin_bits, rows=rows,
+                interpret=interpret)
+        # pad words beyond the reference tile count are all-zero (zero pad
+        # in, zero bins out) — truncate to the canonical wire length
+        words = words2d.reshape(-1)[:C.packed_word_count(n, cfg.bin_bits)]
+        outlier = out2d.reshape(-1)[:n]
 
-    n_out = jnp.sum(outlier).astype(jnp.int32)
-    (idx,) = jnp.nonzero(outlier, size=k, fill_value=n)
-    safe_idx = jnp.minimum(idx, n - 1)
-    payload = jnp.where(idx < n, float_to_bits(flat)[safe_idx], 0)
+    n_out, idx, payload = C.compact_outliers(flat, outlier, k)
     return C.EncodedPacked(words, idx.astype(jnp.int32),
                            payload.astype(jnp.uint32), n_out, n_out > k,
                            sign_words,
@@ -402,6 +404,11 @@ def decode_packed(enc: C.EncodedPacked, cfg: QuantizerConfig, n=None,
         if shape is None:
             raise ValueError("decode_packed needs n or shape")
         n = int(np.prod(shape))
+    with obs.scope("codec.dequantize"):
+        return _decode_packed(enc, cfg, n, shape, dtype, rows, interpret)
+
+
+def _decode_packed(enc, cfg, n, shape, dtype, rows, interpret):
     dt = jnp.dtype(dtype or cfg.dtype)
     vpw = 32 // cfg.bin_bits
     if cfg.mode == "rel":

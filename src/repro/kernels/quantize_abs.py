@@ -89,4 +89,5 @@ def quantize_abs_pallas(x2d: jnp.ndarray, eb: jnp.ndarray, *, maxbin: int,
             jax.ShapeDtypeStruct((r_total, LANES), dt),
         ],
         interpret=interpret,
+        name="quantize_abs",
     )(x2d, eb)

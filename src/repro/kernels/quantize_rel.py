@@ -96,4 +96,5 @@ def quantize_rel_pallas(x2d: jnp.ndarray, *, cfg, rows: int = DEFAULT_ROWS,
             jax.ShapeDtypeStruct((r_total, LANES), jnp.bool_),
         ],
         interpret=interpret,
+        name="quantize_rel",
     )(x2d)

@@ -50,6 +50,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.core import QuantizerConfig
 from repro.core import audit as A
@@ -139,7 +140,7 @@ class DecodeEngine:
         self._tok = jnp.zeros((self.n_slots, 1, 1), jnp.int32)
         self.requests: list = [None] * self.n_slots   # host-side slot table
         self._stats = dict(prefill_tokens=0, generated_tokens=0, steps=0,
-                           wire_bytes=0.0, sends=0, inserts=0, evictions=0,
+                           wire_bytes=0.0, inserts=0,
                            audit_checks=0, audit_failures=0,
                            audit_reports=0, audit_violations=0,
                            audit_nonfinite=0, audit_overflow=0,
@@ -161,12 +162,13 @@ class DecodeEngine:
         slots (their cache/pos/token must not drift while free)."""
         logits, new = jax.vmap(
             self._one_step, in_axes=(None, 0, 0, 0))(params, cache, tok, pos)
-        keep = lambda n, o: jnp.where(
-            live.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
-        new = jax.tree.map(keep, new, cache)
-        nxt = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
-        tok = jnp.where(live, nxt, tok[:, 0, 0]).reshape(-1, 1, 1)
-        pos = jnp.where(live, pos + 1, pos)
+        with obs.scope("serve.slots"):
+            keep = lambda n, o: jnp.where(
+                live.reshape((-1,) + (1,) * (n.ndim - 1)), n, o)
+            new = jax.tree.map(keep, new, cache)
+            nxt = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
+            tok = jnp.where(live, nxt, tok[:, 0, 0]).reshape(-1, 1, 1)
+            pos = jnp.where(live, pos + 1, pos)
         return logits[:, 0], tok, pos, new
 
     # --- slot lifecycle ---------------------------------------------------
@@ -178,21 +180,25 @@ class DecodeEngine:
                 return slot
         return None
 
-    def prefill(self, prompt) -> PrefillResult:
+    def prefill(self, prompt, *, request=None) -> PrefillResult:
         """Run one request's prompt through the batch-1 `serve_step` chain
         and emit the slot-insert wire: closed pages leave as `PackedKV`
-        (per-page chain `self.stages`), the open hot page rides raw."""
+        (per-page chain `self.stages`), the open hot page rides raw.
+        `request` only labels the `engine.prefill` span."""
         prompt = jnp.asarray(prompt, jnp.int32).reshape(-1)
         m = int(prompt.shape[0])
         assert 0 < m < self.seq, (m, self.seq)
-        cache = S.make_quant_cache(self.cfg, 1, self.seq)
-        logits = None
-        for i in range(m):
-            logits, cache = self._step1(self.params, cache,
-                                        prompt[i].reshape(1, 1),
-                                        jnp.int32(i))
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32).reshape(1, 1)
-        wire = self._seal(S.pack_cache(cache, stages=self.stages))
+        with obs.span("engine.prefill", request=request, tokens=m):
+            cache = S.make_quant_cache(self.cfg, 1, self.seq)
+            logits = None
+            with obs.span("engine.prefill.tokens", request=request):
+                for i in range(m):
+                    logits, cache = self._step1(self.params, cache,
+                                                prompt[i].reshape(1, 1),
+                                                jnp.int32(i))
+            with obs.span("engine.prefill.pack", request=request):
+                nxt = jnp.argmax(logits, -1).astype(jnp.int32).reshape(1, 1)
+                wire = self._seal(S.pack_cache(cache, stages=self.stages))
         self._stats["prefill_tokens"] += m
         return PrefillResult(wire, nxt, logits, m)
 
@@ -240,12 +246,17 @@ class DecodeEngine:
         assert self.requests[slot] is None, f"slot {slot} is live"
         assert isinstance(pre.pages.k, KVC.PackedKV), type(pre.pages.k)
         assert isinstance(pre.pages.v, KVC.PackedKV), type(pre.pages.v)
-        self._account(pre.pages)
-        if not self._verify_pages(slot, pre.pages):
-            return False
-        self.insert_cache(slot, S.unpack_cache(pre.pages),
-                          next_token=pre.next_token, pos=pre.pos,
-                          request=request)
+        # `request=True` is the slot table's plain marker, not an id
+        rid = None if isinstance(request, bool) else request
+        with obs.span("engine.insert", slot=slot, request=rid):
+            self._account(pre.pages)
+            with obs.span("engine.insert.verify", slot=slot, request=rid):
+                if not self._verify_pages(slot, pre.pages):
+                    return False
+            with obs.span("engine.insert.unpack", slot=slot, request=rid):
+                self.insert_cache(slot, S.unpack_cache(pre.pages),
+                                  next_token=pre.next_token, pos=pre.pos,
+                                  request=request)
         return True
 
     def insert_cache(self, slot: int, cache1: S.QuantCache, *,
@@ -273,12 +284,17 @@ class DecodeEngine:
         live = [r is not None for r in self.requests]
         if not any(live):
             raise RuntimeError("generate_step with no live slot")
-        for slot, on in enumerate(live):
-            assert not on or int(self._pos[slot]) < self.seq, (
-                f"slot {slot} ran past seq={self.seq}; release it first")
-        logits, self._tok, self._pos, self._cache = self._vstep(
-            self.params, self._cache, self._tok, self._pos,
-            jnp.asarray(live))
+        with obs.span("engine.generate_step", live=sum(live)):
+            with obs.span("engine.step.slot_check"):
+                for slot, on in enumerate(live):
+                    assert not on or int(self._pos[slot]) < self.seq, (
+                        f"slot {slot} ran past seq={self.seq}; release it "
+                        f"first")
+                live_mask = jnp.asarray(live)
+            with obs.span("engine.step.dispatch"):
+                logits, self._tok, self._pos, self._cache = self._vstep(
+                    self.params, self._cache, self._tok, self._pos,
+                    live_mask)
         self._stats["steps"] += 1
         self._stats["generated_tokens"] += sum(live)
         return logits, self._tok[:, 0, 0]
@@ -288,12 +304,12 @@ class DecodeEngine:
         preemption / rebalancing) and free it.  The result re-`insert`s
         into any engine bit-exactly."""
         assert self.requests[slot] is not None, f"slot {slot} is free"
-        cache1 = jax.tree.map(lambda full: full[slot], self._cache)
-        wire = self._seal(S.pack_cache(cache1, stages=self.stages))
-        out = PrefillResult(wire, self._tok[slot], None,
-                            int(self._pos[slot]))
+        with obs.span("engine.evict", slot=slot):
+            cache1 = jax.tree.map(lambda full: full[slot], self._cache)
+            wire = self._seal(S.pack_cache(cache1, stages=self.stages))
+            out = PrefillResult(wire, self._tok[slot], None,
+                                int(self._pos[slot]))
         self._account(wire)
-        self._stats["evictions"] += 1
         self.release(slot)
         return out
 
@@ -306,7 +322,6 @@ class DecodeEngine:
     def _account(self, wire):
         moved = float(self.transport.bytes_moved(wire, op="send_pages"))
         self._stats["wire_bytes"] += moved
-        self._stats["sends"] += 1
         return moved
 
     def raw_slot_bytes(self) -> int:
@@ -364,7 +379,6 @@ class DecodeEngine:
                                       next_token=pre.next_token,
                                       pos=pre.pos, request=rid)
                     self._stats["wire_bytes"] += pre.stats["wire_bytes"]
-                    self._stats["sends"] += pre.stats["sends"]
                 else:
                     self.insert(slot, pre, request=rid)
                 out[rid].append(int(jnp.reshape(pre.next_token, ())))
@@ -449,24 +463,28 @@ def stream_prefill(cfg: ArchConfig, params, prompt, *, seq: int, mesh,
     cache = S.make_quant_cache(cfg, 1, seq)
     ledger, inflight = [], []
     logits = None
-    for i in range(m):
-        logits, cache = step(params, cache, prompt[i].reshape(1, 1),
-                             jnp.int32(i))
-        if (i + 1) % S.PAGE == 0:
-            p = i // S.PAGE
-            wire = PageWire(
-                KVC.pack_kv(KVC.slice_pages(cache.k, p, page=S.PAGE),
-                            page=S.PAGE, stages=stages),
-                KVC.pack_kv(KVC.slice_pages(cache.v, p, page=S.PAGE),
-                            page=S.PAGE, stages=stages))
-            # async dispatch: this send overlaps the next page's prefill
-            inflight.append((p, send(wire)))
-            ledger.append(("PageWire", p,
-                           float(tp.bytes_moved(wire, op="send_pages"))))
-    tail = TailWire(cache.hot_k, cache.hot_v, logits)
-    got_tail = take(send(tail))
-    ledger.append(("TailWire", m // S.PAGE,
-                   float(tp.bytes_moved(tail, op="send_pages"))))
+    with obs.span("engine.stream_prefill", tokens=m, src=src, dst=dst):
+        for i in range(m):
+            logits, cache = step(params, cache, prompt[i].reshape(1, 1),
+                                 jnp.int32(i))
+            if (i + 1) % S.PAGE == 0:
+                p = i // S.PAGE
+                with obs.span("transport.send_pages", page=p):
+                    wire = PageWire(
+                        KVC.pack_kv(KVC.slice_pages(cache.k, p, page=S.PAGE),
+                                    page=S.PAGE, stages=stages),
+                        KVC.pack_kv(KVC.slice_pages(cache.v, p, page=S.PAGE),
+                                    page=S.PAGE, stages=stages))
+                    # async dispatch: this send overlaps the next page's
+                    # prefill
+                    inflight.append((p, send(wire)))
+                ledger.append(("PageWire", p,
+                               float(tp.bytes_moved(wire, op="send_pages"))))
+        tail = TailWire(cache.hot_k, cache.hot_v, logits)
+        with obs.span("transport.send_pages", page=m // S.PAGE):
+            got_tail = take(send(tail))
+        ledger.append(("TailWire", m // S.PAGE,
+                       float(tp.bytes_moved(tail, op="send_pages"))))
 
     # --- decode host: assemble the slot cache from the received wires ---
     recv = S.make_quant_cache(cfg, 1, seq)
@@ -479,7 +497,7 @@ def stream_prefill(cfg: ArchConfig, params, prompt, *, seq: int, mesh,
                             page=S.PAGE)
     assembled = S.QuantCache(k, v, got_tail.hot_k, got_tail.hot_v)
     nxt = jnp.argmax(got_tail.logits, -1).astype(jnp.int32).reshape(1, 1)
-    stats = dict(wire_bytes=sum(b for *_, b in ledger), sends=len(ledger),
+    stats = dict(wire_bytes=sum(b for *_, b in ledger),
                  pages_streamed=len(inflight), ledger=ledger,
                  prefill_tokens=m)
     return StreamedPrefill(assembled, nxt, got_tail.logits, m, stats)

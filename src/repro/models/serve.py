@@ -30,6 +30,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ArchConfig
 from repro.core import QuantizerConfig
 from repro.core.transport import TRANSPORT, Transport
@@ -183,8 +184,9 @@ def _attn_decode_quant(cfg: ArchConfig, p, x, qk, qv, hot_k, hot_v, pos,
         hot_v, v.astype(hot_v.dtype), (0, in_page, 0, 0))
 
     # attention = closed (quantized) pages + open (hot) page
-    hist_k = KVC.dequantize_kv(qk, page=PAGE, dtype=DTYPE)   # [B,G,S,hd]
-    hist_v = KVC.dequantize_kv(qv, page=PAGE, dtype=DTYPE)
+    with obs.scope("serve.kv_dequant"):
+        hist_k = KVC.dequantize_kv(qk, page=PAGE, dtype=DTYPE)  # [B,G,S,hd]
+        hist_v = KVC.dequantize_kv(qv, page=PAGE, dtype=DTYPE)
     page_start = (pos // PAGE) * PAGE
     hist_len = jnp.full((b,), page_start, jnp.int32)
     hot_len = jnp.full((b,), in_page + 1, jnp.int32)
@@ -202,13 +204,14 @@ def _attn_decode_quant(cfg: ArchConfig, p, x, qk, qv, hot_k, hot_v, pos,
 
     # close the page when it fills
     kv_c = kv_cfg
-    qk, qv, hot_k, hot_v = jax.lax.cond(
-        (pos + 1) % PAGE == 0,
-        lambda a: (_quantize_page(a[0], a[2], pos // PAGE, kv_c),
-                   _quantize_page(a[1], a[3], pos // PAGE, kv_c),
-                   jnp.zeros_like(a[2]), jnp.zeros_like(a[3])),
-        lambda a: a,
-        (qk, qv, hot_k, hot_v))
+    with obs.scope("serve.page_close"):
+        qk, qv, hot_k, hot_v = jax.lax.cond(
+            (pos + 1) % PAGE == 0,
+            lambda a: (_quantize_page(a[0], a[2], pos // PAGE, kv_c),
+                       _quantize_page(a[1], a[3], pos // PAGE, kv_c),
+                       jnp.zeros_like(a[2]), jnp.zeros_like(a[3])),
+            lambda a: a,
+            (qk, qv, hot_k, hot_v))
     return x + o @ p["wo"], qk, qv, hot_k, hot_v
 
 
@@ -240,38 +243,53 @@ def _ffn_decode(cfg: ArchConfig, p, x, mesh):
 def serve_step(cfg: ArchConfig, params, cache, tokens, pos, mesh=None,
                kv_cfg: QuantizerConfig | None = None):
     """One decode step.  tokens: int32 [B, 1]; pos: scalar int32 (aligned
-    batch).  Returns (logits [B, V] f32, new_cache)."""
-    x = params["emb"][tokens].astype(DTYPE)
+    batch).  Returns (logits [B, V] f32, new_cache).
+
+    Device scopes (DESIGN.md §14): `serve.embed`, `serve.layers` (the
+    layer scan: per-layer slices and stacked cache writes), inside it
+    `serve.attn` (with `serve.kv_dequant`, `serve.page_close`) and
+    `serve.ffn`, then `serve.lm_head`."""
+    with obs.scope("serve.embed"):
+        x = params["emb"][tokens].astype(DTYPE)
 
     if cfg.family == "hybrid":
-        x, cache = _serve_hybrid(cfg, params, cache, x, pos, mesh)
+        with obs.scope("serve.layers"):
+            x, cache = _serve_hybrid(cfg, params, cache, x, pos, mesh)
     elif isinstance(cache, QuantCache):
         assert kv_cfg is not None
 
         def body(h, xs):
             lp, qk, qv, hk, hv = xs      # scan slices the leading L axis
-            h, qk, qv, hk, hv = _attn_decode_quant(
-                cfg, lp, h, qk, qv, hk, hv, pos, kv_cfg)
-            h = _ffn_decode(cfg, lp, h, mesh)
+            with obs.scope("serve.attn"):
+                h, qk, qv, hk, hv = _attn_decode_quant(
+                    cfg, lp, h, qk, qv, hk, hv, pos, kv_cfg)
+            with obs.scope("serve.ffn"):
+                h = _ffn_decode(cfg, lp, h, mesh)
             return h, (qk, qv, hk, hv)
 
-        x, (qk, qv, hk, hv) = jax.lax.scan(
-            body, x, (params["layers"], cache.k, cache.v,
-                      cache.hot_k, cache.hot_v))
+        with obs.scope("serve.layers"):
+            x, (qk, qv, hk, hv) = jax.lax.scan(
+                body, x, (params["layers"], cache.k, cache.v,
+                          cache.hot_k, cache.hot_v))
         cache = QuantCache(qk, qv, hk, hv)
     else:
         def body(h, xs):
             lp, kc, vc = xs
-            h, kc, vc = _attn_decode_raw(cfg, lp, h, kc, vc, pos)
-            h = _ffn_decode(cfg, lp, h, mesh)
+            with obs.scope("serve.attn"):
+                h, kc, vc = _attn_decode_raw(cfg, lp, h, kc, vc, pos)
+            with obs.scope("serve.ffn"):
+                h = _ffn_decode(cfg, lp, h, mesh)
             return h, (kc, vc)
 
-        x, (kc, vc) = jax.lax.scan(body, x,
-                                   (params["layers"], cache.k, cache.v))
+        with obs.scope("serve.layers"):
+            x, (kc, vc) = jax.lax.scan(body, x,
+                                       (params["layers"], cache.k, cache.v))
         cache = RawCache(kc, vc)
 
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["emb"].T.astype(DTYPE))[:, 0].astype(jnp.float32)
+    with obs.scope("serve.lm_head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = (x @ params["emb"].T.astype(DTYPE))[:, 0].astype(
+            jnp.float32)
     return logits, cache
 
 
@@ -288,7 +306,8 @@ def _serve_hybrid(cfg: ArchConfig, params, cache, x, pos, mesh):
         for blk in range(n_per):
             if blk == n_per - 1:
                 ap = pp["attn"]
-                h, kc, vc = _attn_decode_raw(cfg, ap, h, kc, vc, pos)
+                with obs.scope("serve.attn"):
+                    h, kc, vc = _attn_decode_raw(cfg, ap, h, kc, vc, pos)
             else:
                 mp = jax.tree.map(lambda t: t[mamba_i], pp["mamba"])
                 hn = L.rms_norm(h, mp["ln1"], cfg.norm_eps)
@@ -304,7 +323,8 @@ def _serve_hybrid(cfg: ArchConfig, params, cache, x, pos, mesh):
             else:
                 fp = jax.tree.map(lambda t: t[dense_i], pp["dense_ffn"])
                 dense_i += 1
-            h = _ffn_decode(cfg, fp, h, mesh)
+            with obs.scope("serve.ffn"):
+                h = _ffn_decode(cfg, fp, h, mesh)
         return h, (kc, vc, jnp.stack(new_tails), jnp.stack(new_hs))
 
     x, (kc, vc, tails, hs) = jax.lax.scan(
