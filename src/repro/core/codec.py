@@ -364,7 +364,7 @@ def compact_outliers(flat: jnp.ndarray, outlier: jnp.ndarray, k: int):
     outlier indices padded with n, their raw bits).  Shared by the
     reference and both fused encoders."""
     n = flat.shape[0]
-    with obs.scope("codec.compact"):
+    with obs.scope("codec.compact"), obs.scope("codec.compact.outliers"):
         n_out = jnp.sum(outlier).astype(jnp.int32)
         (idx,) = jnp.nonzero(outlier, size=k, fill_value=n)
         safe_idx = jnp.minimum(idx, n - 1)
@@ -428,13 +428,19 @@ def _decode_packed(enc, cfg, n, shape, dtype, bin_untransform):
 # padded-to-capacity (n_chunks * LC_CHUNK words) with the used word count
 # transmitted in `payload_len` — a real transport moves only payload_len
 # words plus the header plane; wire_bits() accounts exactly that.
+# Every code's payload is 0, 1, 2 or 4 whole 128-lane rows, so the
+# payload is placed and read back by rows from one per-chunk row offset
+# (lc_row_offsets), never word by word.
 # encode 'stage' selects the mode: 'zero' restricts codes to {0, 3} (zero
 # suppression only), 'narrow' uses the full set.
 
 LC_CHUNK = 512                 # words per chunk (4 x PACK_LANES)
+LC_CHUNK_ROWS = LC_CHUNK // PACK_LANES
 LC_STAGES = ("zero", "narrow")
 _LC_WIDTHS = (0, 8, 16, 32)    # stored word width per header code
 _LC_LENS = tuple(LC_CHUNK * w // 32 for w in _LC_WIDTHS)   # payload words
+# every payload is whole 128-lane rows: code c < 3 holds c rows
+assert _LC_LENS == (0, PACK_LANES, 2 * PACK_LANES, LC_CHUNK)
 
 
 def transmitted_bits(payload_len, static_bits: int):
@@ -514,11 +520,12 @@ def compact_chunks(sel: jnp.ndarray, lens: jnp.ndarray):
     uint32[n_chunks, LC_CHUNK] (each chunk's payload left-aligned), lens:
     int32[n_chunks] words used per chunk (<= LC_CHUNK).  Returns (payload
     uint32[n_chunks * LC_CHUNK] with the tail zero, payload_len int32
-    scalar — the words a real transport moves).  Shared by the zero/
-    narrow chunk coder and the `ent` entropy stage."""
+    scalar — the words a real transport moves).  Word-granular: the `ent`
+    stage's lengths are arbitrary; the zero/narrow coder moves whole rows
+    (lc_compact_payload)."""
     n_chunks = sel.shape[0]
     cap = n_chunks * LC_CHUNK
-    with obs.scope("codec.compact"):
+    with obs.scope("codec.compact"), obs.scope("codec.compact.chunks"):
         ends = jnp.cumsum(lens)
         offs = ends - lens
         slot = jnp.arange(LC_CHUNK, dtype=jnp.int32)[None, :]
@@ -545,30 +552,77 @@ def gather_chunks(payload: jnp.ndarray, lens: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(valid, payload[src], jnp.uint32(0))
 
 
+def lc_row_offsets(codes: jnp.ndarray):
+    """Where each chunk's payload lies, in 128-word rows: (rows int32
+    [n_chunks] in {0, 1, 2, 4}, first row int32[n_chunks], total rows
+    int32 scalar).  The one source of the offsets for both the
+    compaction and its inverse gather."""
+    # code c < 3 holds c rows (_LC_LENS): arithmetic, not a table lookup
+    rows = jnp.where(codes == 3, LC_CHUNK_ROWS, codes).astype(jnp.int32)
+    ends = jnp.cumsum(rows)
+    return rows, ends - rows, ends[-1]
+
+
 def lc_compact_payload(sel: jnp.ndarray, codes: jnp.ndarray):
-    """compact_chunks with the §6 per-code chunk lengths."""
-    return compact_chunks(sel, lc_chunk_lens(codes))
+    """compact_chunks with the §6 per-code chunk lengths, moving whole
+    rows: chunk c's rows j < rows_c land at row first_c + j.  Each of the
+    LC_CHUNK_ROWS scatters writes unique rows (rows of one chunk never
+    overlap another's); a row past its chunk's length goes to a row of
+    its own past the end, and is dropped.
+    Bit-identical to compact_chunks(sel, lc_chunk_lens(codes))."""
+    n_chunks = sel.shape[0]
+    n_rows = n_chunks * LC_CHUNK_ROWS
+    with obs.scope("codec.compact"), obs.scope("codec.compact.chunks"):
+        rows, first, total = lc_row_offsets(codes)
+        src = sel.reshape(n_chunks, LC_CHUNK_ROWS, PACK_LANES)
+        past = n_rows + jnp.arange(n_chunks, dtype=jnp.int32)
+        payload = jnp.zeros((n_rows, PACK_LANES), jnp.uint32)
+        for j in range(LC_CHUNK_ROWS):
+            dest = jnp.where(j < rows, first + j, past)
+            payload = payload.at[dest].set(src[:, j], mode="drop",
+                                           unique_indices=True)
+    return payload.reshape(-1), total * PACK_LANES
+
+
+def _lc_payload_rows(payload: jnp.ndarray, codes: jnp.ndarray,
+                     j: jnp.ndarray) -> jnp.ndarray:
+    """Row j[c, b] of chunk c's payload for each of its LC_CHUNK_ROWS
+    slots b, one 128-word row per index: uint32[n_chunks, LC_CHUNK_ROWS,
+    PACK_LANES].  A slot whose j is not below the chunk's row count reads
+    zero.  A header that claims more rows than the payload holds (a
+    payload cut short) reads the payload's last row again: deterministic,
+    and never out of bounds."""
+    prows = payload.reshape(-1, PACK_LANES)
+    rows, first, _ = lc_row_offsets(codes)
+    src = jnp.clip(first[:, None] + j, 0, prows.shape[0] - 1)
+    return jnp.where((j < rows[:, None])[..., None], prows[src],
+                     jnp.uint32(0))
 
 
 def lc_gather_chunks(payload: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
-    """Inverse of lc_compact_payload: re-pad each chunk's narrowed words to
+    """Inverse of lc_compact_payload: re-pad each chunk's narrowed rows to
     LC_CHUNK slots.  Returns uint32[n_chunks, LC_CHUNK]."""
-    return gather_chunks(payload, lc_chunk_lens(codes))
+    j = jnp.arange(LC_CHUNK_ROWS, dtype=jnp.int32)[None, :]
+    return _lc_payload_rows(payload, codes, j).reshape(-1, LC_CHUNK)
 
 
-def lc_expand_chunks(padded: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
-    """Widen narrowed chunks back to uint32 words (exact inverse of
-    lc_narrow_chunks for the valid prefix)."""
-    n_chunks = padded.shape[0]
-    flat_n = n_chunks * LC_CHUNK
-    exp1 = unpack_words(padded[:, :LC_CHUNK // 4].reshape(-1), flat_n, 8,
-                        signed=False).reshape(n_chunks, LC_CHUNK)
-    exp2 = unpack_words(padded[:, :LC_CHUNK // 2].reshape(-1), flat_n, 16,
-                        signed=False).reshape(n_chunks, LC_CHUNK)
+def lc_expand_payload(payload: jnp.ndarray, codes: jnp.ndarray) -> jnp.ndarray:
+    """The chunks' words straight from the payload: lc_gather_chunks and
+    the widening in one pass, one 128-word row per index.  At width w a
+    payload row holds 32/w word rows (pack_words' layout), so word row b
+    of a chunk is payload row b // (32/w) of it, shifted right by
+    w * (b % (32/w)) and masked to w bits.  Returns uint32[n_chunks,
+    LC_CHUNK]."""
     c = codes[:, None]
-    return jnp.where(c == 1, exp1,
-                     jnp.where(c == 2, exp2,
-                               jnp.where(c == 3, padded, jnp.uint32(0))))
+    b = jnp.arange(LC_CHUNK_ROWS, dtype=jnp.int32)[None, :]
+    per = jnp.maximum(3 - c, 0)            # log2 of word rows a payload row
+    width = jnp.left_shift(4, c)           # codes 1, 2, 3: 8, 16, 32 bits
+    shift = ((b & ((1 << per) - 1)) * width).astype(jnp.uint32)
+    mask = jnp.where(c == 3, jnp.uint32(0xFFFFFFFF),
+                     (jnp.uint32(1) << width.astype(jnp.uint32)) - 1)
+    rows = _lc_payload_rows(payload, codes, b >> per)
+    out = (rows >> shift[..., None]) & mask[..., None]
+    return out.reshape(-1, LC_CHUNK)
 
 
 def encode_words_lc(words: jnp.ndarray, stage: str = "narrow"):
@@ -592,8 +646,7 @@ def decode_words_lc(header_words: jnp.ndarray, payload: jnp.ndarray,
     n_chunks = lc_chunk_count(n_words)
     codes = unpack_words(header_words, n_chunks, 2,
                          signed=False).astype(jnp.int32)
-    padded = lc_gather_chunks(payload, codes)
-    return lc_expand_chunks(padded, codes).reshape(-1)[:n_words]
+    return lc_expand_payload(payload, codes).reshape(-1)[:n_words]
 
 
 class EncodedLC(NamedTuple):

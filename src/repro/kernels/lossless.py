@@ -15,15 +15,19 @@ zero/narrow scheme of DESIGN.md §6 (reference: core.codec.encode_words_lc):
     of kernels/pack.py with the chunk scan, so x is read ONCE from HBM
     and what comes back is already the narrowed chunk image + the 2-bit
     header codes — the lossless stage rides the existing memory stream;
-  * the variable-length compaction (cumsum of chunk lengths + scatter)
-    and its inverse gather are NOT kernels: they are cheap O(n_words)
-    XLA ops over the narrowed intermediate, shared verbatim with the
-    reference (core.codec.lc_compact_payload / lc_gather_chunks), which
-    is what makes kernel and reference bit-identical by construction.
+  * the variable-length compaction and its inverse gather are XLA ops
+    shared verbatim with the reference (core.codec.lc_compact_payload /
+    lc_gather_chunks, whose row gather the reference's one-pass decode
+    lc_expand_payload also runs), which is what makes kernel and
+    reference bit-identical by construction.  They move whole 128-word
+    rows from one per-chunk row offset: moved one word per index, they
+    were not cheap — on a v5e over a 512^3 field the word gather took
+    54% of the codec's device time, about 26 ns a word, and the encode's
+    compaction with the outlier table 44% (PERF.md §5).
 
 Everything validates in interpret mode on CPU (tests/test_lossless.py),
 compiles for a TPU v5e (tests/test_tpu_compile.py) and runs bit-identical
-to the reference on one (chip_smoke.py); its speed is not measured yet.
+to the reference on one (chip_smoke.py); PERF.md has its speed.
 """
 from __future__ import annotations
 
@@ -232,7 +236,7 @@ def decode_words_lc(header_words, payload, n_words, *, wrows=DEFAULT_ROWS,
     n_chunks = C.lc_chunk_count(n_words)
     codes = C.unpack_words(header_words, n_chunks, 2,
                            signed=False).astype(jnp.int32)
-    padded = C.lc_gather_chunks(payload, codes)            # XLA gather
+    padded = C.lc_gather_chunks(payload, codes)            # XLA row gather
     p2d = _tile_words(padded.reshape(-1), wrows)
     blocks = p2d.shape[0] // wrows
     c_need = blocks * (wrows // CHUNK_ROWS)

@@ -5,6 +5,8 @@ honest wire accounting through the gradient and KV wires.
 Everything here is a bit-equality test: the lossless stage sits between
 quantize+pack and the collective, so ANY discrepancy — one word, one chunk
 code — is a guarantee violation, not a quality regression."""
+import re
+
 import numpy as np
 import pytest
 
@@ -117,6 +119,142 @@ def test_words_lc_roundtrip_property(stage):
         np.testing.assert_array_equal(back, w)
 
     run()
+
+
+# ------------------------------------------ row-granular LC payload mover --
+
+def _coded_stream(codes, n=None):
+    """Words whose chunks take exactly `codes` under the narrow stage."""
+    codes = np.asarray(codes)
+    top = np.array([0, (1 << 8) - 1, (1 << 16) - 1, (1 << 32) - 1],
+                   np.uint64)[codes]
+    w = RNG.integers(0, 1 << 32, (codes.size, LC_CHUNK), dtype=np.uint64)
+    w = w % (top[:, None] + 1)
+    w[:, 0] = top                          # each chunk's widest word
+    w = w.reshape(-1).astype(np.uint32)
+    return w if n is None else w[:n]
+
+
+ROW_CASES = {
+    # name: (words, payload rows handed to the gather; None = all)
+    "allzero": lambda: (_coded_stream([0] * 4), None),
+    "allfull": lambda: (_coded_stream([3] * 4), None),
+    "alternating": lambda: (_coded_stream([1, 2, 3] * 3), None),
+    "last_only": lambda: (_coded_stream([0] * 5 + [3]), None),
+    "ragged": lambda: (_coded_stream([2, 0, 3, 1, 3], 4 * LC_CHUNK + 77),
+                       None),
+    # a header that claims 16 rows over a payload cut short to 6
+    "overlong": lambda: (_coded_stream([3] * 4), 6),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROW_CASES))
+def test_lc_row_mover_matches_word_mover(case):
+    """lc_compact_payload / lc_gather_chunks move whole 128-word rows; on
+    the same codes they give the word-granular compact_chunks /
+    gather_chunks' payload, payload_len and chunks bit for bit, and
+    lc_expand_payload the widened words.  Where the header claims more
+    rows than the payload holds, the gathers read the payload's last row
+    again and zero past each chunk's length: deterministic, and nothing
+    outside the payload is read."""
+    from repro.core import codec as C
+
+    words, keep_rows = ROW_CASES[case]()
+    n = words.size
+    n_chunks = lc_chunk_count(n)
+    chunks = jnp.pad(jnp.asarray(words),
+                     (0, n_chunks * LC_CHUNK - n)).reshape(n_chunks, LC_CHUNK)
+    codes = C.lc_chunk_codes(chunks, "narrow")
+    sel = C.lc_narrow_chunks(chunks, codes)
+    lens = C.lc_chunk_lens(codes)
+
+    payload, plen = C.lc_compact_payload(sel, codes)
+    ref_payload, ref_plen = C.compact_chunks(sel, lens)
+    np.testing.assert_array_equal(np.asarray(payload),
+                                  np.asarray(ref_payload))
+    assert int(plen) == int(ref_plen) == int(np.sum(lens))
+
+    given = payload if keep_rows is None else payload[:keep_rows * 128]
+    got = np.asarray(C.lc_gather_chunks(given, codes))
+    np.testing.assert_array_equal(
+        got, np.asarray(jax.jit(C.lc_gather_chunks)(given, codes)))
+    # the row model: chunk c's row j is payload row first_c + j, clamped
+    # to the last row the payload holds, and zero from row lens_c/128 on
+    prows = np.asarray(given).reshape(-1, 128)
+    nrows = np.asarray(lens) // 128
+    first = np.cumsum(nrows) - nrows
+    model = np.zeros((n_chunks, 4, 128), np.uint32)
+    for c in range(n_chunks):
+        for j in range(nrows[c]):
+            model[c, j] = prows[min(first[c] + j, prows.shape[0] - 1)]
+    np.testing.assert_array_equal(got, model.reshape(n_chunks, LC_CHUNK))
+    # the decode gathers and widens in one pass: a width-w row holds 32/w
+    # word rows (pack_words' layout), row b at shift w * (b % (32/w))
+    codes_np = np.asarray(codes)
+    wide = np.zeros_like(model)
+    for c in range(n_chunks):
+        if codes_np[c]:
+            w = (0, 8, 16, 32)[codes_np[c]]
+            for b in range(4):
+                row = model[c, b // (32 // w)].astype(np.uint64)
+                wide[c, b] = (row >> (w * (b % (32 // w)))) & ((1 << w) - 1)
+    np.testing.assert_array_equal(
+        np.asarray(C.lc_expand_payload(given, codes)),
+        wide.reshape(n_chunks, LC_CHUNK))
+    if keep_rows is None:
+        np.testing.assert_array_equal(
+            got, np.asarray(C.gather_chunks(payload, lens)))
+        hw = C.pack_words(codes, 2)
+        np.testing.assert_array_equal(
+            np.asarray(decode_words_lc(hw, payload, n)), words)
+
+
+def _hlo_windows(fn, *args):
+    """(op, words per window) of every gather and scatter in fn's HLO."""
+    txt = jax.jit(fn).lower(*args).compiler_ir("hlo").as_hlo_text()
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?(\S+) = \w+\[([\d,]*)\]",
+                             txt, re.M))
+    dims = lambda spec: [int(d) for d in spec.split(",") if d]
+    out = []
+    for line in txt.splitlines():
+        m = re.search(r" (gather|scatter)\(([^)]*)\)", line)
+        if not m:
+            continue
+        if m.group(1) == "gather":
+            win = dims(re.search(r"slice_sizes=\{([\d,]*)\}",
+                                 line).group(1))
+        else:
+            upd = dims(shapes[m.group(2).split(",")[-1].strip()])
+            win = [upd[i] for i in dims(re.search(
+                r"update_window_dims=\{([\d,]*)\}", line).group(1))]
+        out.append((m.group(1), int(np.prod(win))))
+    return out
+
+
+@pytest.mark.parametrize("impl", ["reference", "kernel"])
+@pytest.mark.parametrize("stage", LC_STAGES)
+def test_lc_payload_moves_whole_rows(stage, impl):
+    """No gather or scatter of the zero/narrow coder, encode or decode,
+    moves less than one 128-word row per index (a per-word mover costs
+    the chip ~26 ns a word)."""
+    from repro.core import codec as C
+
+    n = 10 * LC_CHUNK + 13
+    w = jax.ShapeDtypeStruct((n,), jnp.uint32)
+    # the reader flags a word-granular mover
+    lens = jax.ShapeDtypeStruct((lc_chunk_count(n),), jnp.int32)
+    cap = jax.ShapeDtypeStruct((lc_chunk_count(n) * LC_CHUNK,), jnp.uint32)
+    assert ("gather", 1) in _hlo_windows(C.gather_chunks, cap, lens)
+    if impl == "reference":
+        enc = lambda x: encode_words_lc(x, stage)
+        dec = lambda h, p: decode_words_lc(h, p, n)
+    else:
+        enc = lambda x: klc.encode_words_lc(x, stage, interpret=True)
+        dec = lambda h, p: klc.decode_words_lc(h, p, n, interpret=True)
+    hw, payload, _ = jax.eval_shape(enc, w)
+    moves = _hlo_windows(enc, w) + _hlo_windows(dec, hw, payload)
+    assert {op for op, _ in moves} == {"gather", "scatter"}, moves
+    assert min(win for _, win in moves) >= 128, moves
 
 
 # ----------------------------------------------- ent word-stream stage ----

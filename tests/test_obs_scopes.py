@@ -82,7 +82,8 @@ def test_pipeline_encode_scopes(kernels):
     pipe = parse_pipeline(CHAIN)
     got = compiled_scopes(
         lambda x: pipe.encode(x, kernels=kernels, interpret=True), X)
-    want = {"codec.quantize_pack", "codec.compact"}
+    want = {"codec.quantize_pack", "codec.compact", "codec.compact.outliers",
+            "codec.compact.chunks"}
     if not kernels:        # the fused kernel narrows inside itself
         want |= {"codec.word_stages", "narrow"}
     assert want <= got, want - got
@@ -165,6 +166,7 @@ def reduce_scopes():
                 "codec.dequantize"}),
     ("ring", {"transport.ring", "transport.gather"}),
     ("mean", {"grads.compress", "codec.quantize_pack", "codec.compact",
+              "codec.compact.outliers", "codec.compact.chunks",
               "transport.gather", "transport.psum_fallback"}),
 ])
 def test_reduce_scopes_under_shard_map(reduce_scopes, case, want):
